@@ -42,6 +42,9 @@ def main(argv=None) -> None:
                              "(open in chrome://tracing or ui.perfetto.dev)")
     args = parser.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(_ROOT)
+
     # SIGTERM mid-benchmark (CI timeout, operator ctrl) gracefully drains
     # any durable server a benchmark has live — clean final snapshot and
     # a closed journal instead of a dead pool and a torn tail

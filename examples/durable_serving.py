@@ -26,14 +26,6 @@ import textwrap
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import jax                                                  # noqa: E402
-import jax.numpy as jnp                                     # noqa: E402
-
-from repro.configs import ARCHS, reduced                    # noqa: E402
-from repro.core.serving import Request, SynergyServer       # noqa: E402
-from repro.models import init_model                         # noqa: E402
-from repro.soc import Durability, RequestJournal            # noqa: E402
-
 N_REQ, NEW_TOKENS, PLEN = 6, 12, 4
 
 #: the worker: a durable server that snapshots every 4 steps and prints
@@ -62,6 +54,8 @@ _WORKER = textwrap.dedent("""
 
 
 def reference(cfg, params):
+    import jax.numpy as jnp
+    from repro.core.serving import Request, SynergyServer
     srv = SynergyServer(cfg, params, slots=3, max_len=32, prefill_len=4)
     reqs = [Request(i, jnp.arange(4, dtype=jnp.int32) + 3 * i,
                     max_new_tokens=NEW_TOKENS) for i in range(N_REQ)]
@@ -72,10 +66,8 @@ def reference(cfg, params):
 
 
 def main():
-    cfg = reduced(ARCHS["granite-3-2b"], n_layers=2, d_model=32,
-                  n_heads=2, d_ff=64, vocab=128)
-    params = init_model(cfg, jax.random.key(0))
-
+    # a device belongs to one process at a time: the parent stays off JAX
+    # (no jax or repro import) until the worker has exited
     workdir = tempfile.mkdtemp(prefix="durable-serving-")
     env = dict(os.environ)
     src = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -92,6 +84,15 @@ def main():
             break
     child.wait()
     print(f"== worker SIGKILLed (rc={child.returncode}) — no cleanup ran")
+
+    import jax
+    from repro.configs import ARCHS, reduced
+    from repro.core.serving import SynergyServer
+    from repro.models import init_model
+    from repro.soc import Durability, RequestJournal
+    cfg = reduced(ARCHS["granite-3-2b"], n_layers=2, d_model=32,
+                  n_heads=2, d_ff=64, vocab=128)
+    params = init_model(cfg, jax.random.key(0))
 
     records, _, torn = RequestJournal.scan(
         os.path.join(workdir, "journal.bin"))

@@ -20,7 +20,7 @@ from repro.configs import ARCHS, reduced
 from repro.configs.base import ArchConfig, ShapeCell
 from repro.data import prefetch, synthetic_batches
 from repro.launch.mesh import make_test_mesh
-from repro.launch.train import build_train_step, make_train_state
+from repro.launch.train import build_train_step, init_train_state
 from repro.runtime import run_with_recovery
 
 PRESETS = {
@@ -76,7 +76,7 @@ def main():
             print(f"resuming from checkpoint step {resume}")
             state = ck.restore(aval)
         else:
-            state = make_train_state(cfg, jax.random.key(0))
+            state = init_train_state(cfg, mesh, jax.random.key(0))
 
         state, failures = run_with_recovery(
             steps=args.steps, run_steps=run_steps, checkpointer=ck,

@@ -276,10 +276,8 @@ def gpipe_spmd(stage_fn: Callable[[Any, jax.Array], jax.Array],
     state0 = jnp.zeros(x_shape, dtype=microbatches.dtype)
     # the loop body makes the carry vary over the stage axis (ppermute /
     # axis_index); mark the initial carry varying so scan types match
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        state0 = pcast(state0, (axis_name,), to="varying")
-        outputs0 = pcast(outputs0, (axis_name,), to="varying")
+    state0 = jax.lax.pcast(state0, (axis_name,), to="varying")
+    outputs0 = jax.lax.pcast(outputs0, (axis_name,), to="varying")
     (_, outputs), _ = jax.lax.scan(tick, (state0, outputs0),
                                    jnp.arange(ticks))
     return outputs

@@ -1701,10 +1701,21 @@ class SynergyServer:
         if (wi is not None and getattr(wi, "ndim", 0) == 3
                 and wi.shape[0] == cfg.n_layers
                 and wi.shape[1] == cfg.d_model):
-            self._decode_ffn_cols = int(wi.shape[2])
-            return jnp.transpose(wi, (1, 0, 2)).reshape(
-                cfg.d_model,
-                cfg.n_layers * self._decode_ffn_cols).astype(jnp.float32)
+            cols = self._decode_ffn_cols = int(wi.shape[2])
+
+            # layer by layer into one buffer: a transpose/reshape/cast
+            # holds a second fp32 copy of every layer's wi on the way
+            def stack(w):
+                def place(l, out):
+                    blk = jax.lax.dynamic_index_in_dim(w, l, 0,
+                                                       keepdims=False)
+                    return jax.lax.dynamic_update_slice(
+                        out, blk.astype(jnp.float32), (0, l * cols))
+                return jax.lax.fori_loop(
+                    0, cfg.n_layers, place,
+                    jnp.zeros((cfg.d_model, cfg.n_layers * cols),
+                              jnp.float32))
+            return jax.jit(stack)(wi)
         self._decode_ffn_cols = None
         return (jax.random.normal(
             jax.random.key(0xD0), (cfg.d_model, 4 * cfg.d_model))

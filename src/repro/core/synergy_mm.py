@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import sys
 import threading
 import warnings
 from typing import Callable, Optional, Union
@@ -40,7 +39,7 @@ from typing import Callable, Optional, Union
 import jax
 
 from repro.engines import (CAP_GRAD, Engine, Telemetry, current_scope_engine,
-                           dispatch_gemm)
+                           dispatch_gemm, under_grad_trace)
 
 from .job import JobSet
 
@@ -113,65 +112,6 @@ def current_trace() -> Optional[SynergyTrace]:
     return getattr(_state, "trace", None)
 
 
-#: jax's forward-mode AD entry points: every differentiation API
-#: (grad/vjp/jvp/linearize) funnels the callee's trace through one of
-#: these frames in jax/_src/interpreters/ad.py
-_AD_FRAME_NAMES = frozenset({"jvpfun", "jvp_subtrace", "linearize", "jvp"})
-
-
-def _ad_machinery_on_stack() -> bool:
-    """The pjit-jvp detection: ``grad(jit(f))`` differentiates the
-    *jaxpr* of ``f``, so inside ``f`` only jit tracers are visible — the
-    tracer walk in :func:`_under_grad_trace` cannot see the outer JVP
-    trace.  But the TRACING of ``f`` still happens while the ad
-    machinery's Python frames are live (pjit traces its callee from
-    inside ``ad.jvpfun`` when the caller is differentiating), so walking
-    the interpreter stack for those frames closes the gap.  Only runs at
-    trace time (operands already known to be Tracers), so the walk costs
-    nothing per executed step.
-
-    Remaining limitation: a jaxpr traced OUTSIDE any grad context and
-    later differentiated (``g = jit(f); g(x); grad(g)(x)`` reuses the
-    cached trace) is routed before differentiation is known — such call
-    sites should still pass ``job_class='train'``."""
-    fr = sys._getframe(1)
-    while fr is not None:
-        code = fr.f_code
-        if (code.co_name in _AD_FRAME_NAMES
-                and code.co_filename.endswith("interpreters/ad.py")):
-            return True
-        fr = fr.f_back
-    return False
-
-
-def _under_grad_trace(*arrays) -> bool:
-    """True when any operand is being traced for differentiation (JVP
-    tracers — ``jax.grad``/``vjp``/``jvp``/``linearize`` all route through
-    forward mode), or when a jit trace is being built FOR differentiation
-    (``grad(jit(f))`` — see :func:`_ad_machinery_on_stack`).  This is the
-    dispatch-level guard that keeps CAP_GRAD-free engines (int8
-    quantized: round/clip kill the weight gradient; Pallas kernels
-    without a VJP rule) off differentiated GEMMs even when no call site
-    asked for grad-safety explicitly."""
-    traced = False
-    pending = [x for x in arrays if x is not None]
-    while pending:
-        x = pending.pop()
-        if not isinstance(x, jax.core.Tracer):
-            continue
-        traced = True
-        names = (type(x).__name__, type(getattr(x, "_trace", x)).__name__)
-        if any("jvp" in n.lower() for n in names):
-            return True
-        # descend through wrapping tracers: JVP carries primal/tangent,
-        # vmap's BatchTracer wraps its inner (possibly JVP) tracer in .val
-        for attr in ("primal", "tangent", "val"):
-            sub = getattr(x, attr, None)
-            if sub is not None:
-                pending.append(sub)
-    return traced and _ad_machinery_on_stack()
-
-
 def _resolve_impl_shim(impl: Optional[str],
                        engine: Union[str, Engine, None]):
     """Translate the legacy ``impl`` string into an engine lookup."""
@@ -219,7 +159,7 @@ def synergy_matmul(a: jax.Array, b: jax.Array, *,
     # grad guard: a GEMM being differentiated may only land on CAP_GRAD
     # engines, whatever the job class said (an int8 pin under jax.grad is
     # a hard error, not a silent zero-gradient).
-    require = (CAP_GRAD,) if _under_grad_trace(a, b, bias) else ()
+    require = (CAP_GRAD,) if under_grad_trace(a, b, bias) else ()
 
     batch = 1
     for d in lead:

@@ -33,7 +33,7 @@ from .sim import SIM_ENGINE_SPECS, SimPEEngine, make_sim_engines
 from .vpu import NeonVpuEngine
 from .dispatch import (DEFAULT_DISPATCHER, JOB_CLASSES, Dispatcher,
                        JobClassPolicy, current_scope_engine, dispatch_gemm,
-                       engine_scope)
+                       engine_scope, under_grad_trace)
 
 __all__ = [
     "Engine", "CostModel", "Telemetry",
@@ -47,7 +47,7 @@ __all__ = [
     "SimPEEngine", "SIM_ENGINE_SPECS", "make_sim_engines",
     "Dispatcher", "DEFAULT_DISPATCHER", "dispatch_gemm",
     "engine_scope", "current_scope_engine",
-    "JobClassPolicy", "JOB_CLASSES",
+    "JobClassPolicy", "JOB_CLASSES", "under_grad_trace",
 ]
 
 

@@ -11,8 +11,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import sys
 import threading
 from typing import Iterable, Iterator, Optional, Union
+
+import jax
 
 from repro.obs import trace as _trace
 
@@ -21,7 +24,7 @@ from .registry import get_engine, list_engines
 
 __all__ = ["Dispatcher", "DEFAULT_DISPATCHER", "dispatch_gemm",
            "engine_scope", "current_scope_engine",
-           "JobClassPolicy", "JOB_CLASSES"]
+           "JobClassPolicy", "JOB_CLASSES", "under_grad_trace"]
 
 _scope = threading.local()
 
@@ -42,6 +45,66 @@ def engine_scope(engine: Union[str, Engine, None]) -> Iterator[None]:
 
 def current_scope_engine() -> Union[str, Engine, None]:
     return getattr(_scope, "engine", None)
+
+
+#: jax's forward-mode AD entry points: every differentiation API
+#: (grad/vjp/jvp/linearize) funnels the callee's trace through one of
+#: these frames in jax/_src/interpreters/ad.py
+_AD_FRAME_NAMES = frozenset({"jvpfun", "jvp_subtrace", "linearize", "jvp"})
+
+
+def _ad_machinery_on_stack() -> bool:
+    """The pjit-jvp detection: ``grad(jit(f))`` differentiates the
+    *jaxpr* of ``f``, so inside ``f`` only jit tracers are visible — the
+    tracer walk in :func:`under_grad_trace` cannot see the outer JVP
+    trace.  But the TRACING of ``f`` still happens while the ad
+    machinery's Python frames are live (pjit traces its callee from
+    inside ``ad.jvpfun`` when the caller is differentiating), so walking
+    the interpreter stack for those frames closes the gap.  Only runs at
+    trace time (operands already known to be Tracers), so the walk costs
+    nothing per executed step.
+
+    Remaining limitation: a jaxpr traced OUTSIDE any grad context and
+    later differentiated (``g = jit(f); g(x); grad(g)(x)`` reuses the
+    cached trace) is routed before differentiation is known — such call
+    sites should still pass ``job_class='train'``."""
+    fr = sys._getframe(1)
+    while fr is not None:
+        code = fr.f_code
+        if (code.co_name in _AD_FRAME_NAMES
+                and code.co_filename.endswith("interpreters/ad.py")):
+            return True
+        fr = fr.f_back
+    return False
+
+
+def under_grad_trace(*arrays) -> bool:
+    """True when any operand is being traced for differentiation (JVP
+    tracers — ``jax.grad``/``vjp``/``jvp``/``linearize`` all route through
+    forward mode), or when a jit trace is being built FOR differentiation
+    (``grad(jit(f))`` — see :func:`_ad_machinery_on_stack`).  This is the
+    dispatch-level guard that keeps CAP_GRAD-free engines (int8
+    quantized: round/clip kill the weight gradient; Pallas kernels
+    without a VJP rule) off differentiated GEMMs even when no call site
+    asked for grad-safety explicitly, and that ``resolve_op(...,
+    grad=True)`` applies to op variants."""
+    traced = False
+    pending = [x for x in arrays if x is not None]
+    while pending:
+        x = pending.pop()
+        if not isinstance(x, jax.core.Tracer):
+            continue
+        traced = True
+        names = (type(x).__name__, type(getattr(x, "_trace", x)).__name__)
+        if any("jvp" in n.lower() for n in names):
+            return True
+        # descend through wrapping tracers: JVP carries primal/tangent,
+        # vmap's BatchTracer wraps its inner (possibly JVP) tracer in .val
+        for attr in ("primal", "tangent", "val"):
+            sub = getattr(x, attr, None)
+            if sub is not None:
+                pending.append(sub)
+    return traced and _ad_machinery_on_stack()
 
 
 @dataclasses.dataclass(frozen=True)

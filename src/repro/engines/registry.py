@@ -140,13 +140,16 @@ class OpVariant:
 
     ``priority`` ranks variants for ``"auto"`` resolution (higher wins);
     ``available`` gates auto-selection (an explicitly named variant always
-    resolves — e.g. Pallas interpret mode off-TPU)."""
+    resolves — e.g. Pallas interpret mode off-TPU); ``grad=False`` marks
+    a variant with no VJP (a Pallas kernel), which auto-selection skips
+    for differentiated operands."""
 
     op: str
     name: str
     fn: Callable
     priority: int = 0
     available: Callable[[], bool] = lambda: True
+    grad: bool = True
 
 
 _OPS: dict[str, dict[str, OpVariant]] = {}
@@ -154,9 +157,11 @@ _OPS: dict[str, dict[str, OpVariant]] = {}
 
 def register_op_impl(op: str, name: str, fn: Callable, *, priority: int = 0,
                      available: Callable[[], bool] | None = None,
+                     grad: bool = True,
                      override: bool = False) -> OpVariant:
     variant = OpVariant(op, name, fn, priority,
-                        available if available is not None else (lambda: True))
+                        available if available is not None else (lambda: True),
+                        grad)
     with _LOCK:
         table = _OPS.setdefault(op, {})
         if name in table and not override:
@@ -171,10 +176,12 @@ def op_variants(op: str) -> list[OpVariant]:
         return sorted(_OPS.get(op, {}).values(), key=lambda v: -v.priority)
 
 
-def resolve_op(op: str, name: str = "auto") -> Callable:
+def resolve_op(op: str, name: str = "auto", *, grad: bool = False) -> Callable:
     """Resolve an op implementation.  ``"auto"`` picks the highest-priority
-    variant whose ``available()`` is true; an explicit name always resolves
-    (KeyError if unknown)."""
+    variant whose ``available()`` is true and, when ``grad`` (the operands
+    are being differentiated — see
+    :func:`repro.engines.dispatch.under_grad_trace`), that has a VJP; an
+    explicit name always resolves (KeyError if unknown)."""
     with _LOCK:
         table = _OPS.get(op)
         if not table:
@@ -187,6 +194,6 @@ def resolve_op(op: str, name: str = "auto") -> Callable:
                                f"known: {sorted(table)}") from None
         ranked = sorted(table.values(), key=lambda v: (-v.priority, v.name))
     for v in ranked:
-        if v.available():
+        if v.available() and (v.grad or not grad):
             return v.fn
     raise RuntimeError(f"no available variant for op {op!r} on this backend")

@@ -20,6 +20,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import kernel_block
+
 from .qmm import qmm_pallas
 from .ref import qmm_ref
 
@@ -49,9 +51,8 @@ def qmm_matmul(a_q: jax.Array, w_q: jax.Array, w_scale: jax.Array, *,
     valid region back out.  ``act_scale`` is a TRACED scalar (the online
     EMA republises a fresh value per live batch; a static arg would
     recompile per decode step) folded into the (1, n) scale operand.
-    ``fuse_dequant=False`` returns raw int32."""
-    if isinstance(tile, int):
-        tile = (tile, tile, tile)
+    ``fuse_dequant=False`` returns raw int32.  The kernel runs the job
+    tile as its :func:`~repro.kernels.kernel_block`."""
     m, k = a_q.shape
     _, n = w_q.shape
     scale = (w_scale.reshape(1, n).astype(jnp.float32)
@@ -60,6 +61,7 @@ def qmm_matmul(a_q: jax.Array, w_q: jax.Array, w_scale: jax.Array, *,
         return qmm_ref(a_q, w_q, scale, bias=bias,
                        activation=activation, out_dtype=out_dtype,
                        fuse_dequant=fuse_dequant)
+    tile = kernel_block(tile)
     ts_m, ts_n, ts_k = tile
     a_p = _pad_to(a_q, (ts_m, ts_k))
     w_p = _pad_to(w_q, (ts_k, ts_n))

@@ -41,9 +41,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["qmm_pallas"]
 
 
@@ -132,7 +129,7 @@ def qmm_pallas(a_q: jax.Array, w_q: jax.Array, scale: jax.Array, *,
         out_specs=pl.BlockSpec((ts_m, ts_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((ts_m, ts_n), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(flops=flops,
                                       bytes_accessed=bytes_accessed,

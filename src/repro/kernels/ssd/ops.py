@@ -10,7 +10,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.engines import register_op_impl, resolve_op
+from repro.engines import register_op_impl, resolve_op, under_grad_trace
 
 from .ssd import ssd_pallas
 from .ref import ssd_ref
@@ -82,7 +82,8 @@ register_op_impl(
     lambda xdt, dta, bm, cm, *, chunk: ssd_pallas(
         xdt, dta, bm, cm, chunk=chunk,
         interpret=jax.default_backend() != "tpu"),
-    priority=10, available=lambda: jax.default_backend() == "tpu")
+    priority=10, available=lambda: jax.default_backend() == "tpu",
+    grad=False)
 register_op_impl(
     "ssd", "xla",
     lambda xdt, dta, bm, cm, *, chunk: ssd_chunked_xla(
@@ -94,7 +95,6 @@ register_op_impl(
     priority=-10)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "impl"))
 def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
         cm: jax.Array, *, chunk: int = 128,
         impl: str = "auto") -> tuple[jax.Array, jax.Array]:
@@ -102,7 +102,16 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
     bm/cm (B,L,N).  Returns y (B,L,H,P) and final state (B,H,P,N).
 
     L is padded up to a chunk multiple with zeros — zero xdt/dta steps are
-    identity for the recurrence (state unchanged), so padding is exact."""
+    identity for the recurrence (state unchanged), so padding is exact.
+    Whether the operands are being differentiated is decided here, outside
+    the jit, so it keys the jit cache: a differentiated call never reuses
+    a trace that resolved a variant without a VJP."""
+    return _ssd(x, dt, a, bm, cm, chunk=chunk, impl=impl,
+                grad=under_grad_trace(x, dt, a, bm, cm))
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "impl", "grad"))
+def _ssd(x, dt, a, bm, cm, *, chunk: int, impl: str, grad: bool):
     l_orig = x.shape[1]
     chunk = min(chunk, max(1, l_orig))
     pad = (-l_orig) % chunk
@@ -111,6 +120,6 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
                                  + [(0, 0)] * (t.ndim - 2))
         x, dt, bm, cm = padl(x), padl(dt), padl(bm), padl(cm)
     xdt, dta = _prescale(x, dt, a)
-    y, s = resolve_op("ssd", impl)(xdt, dta, bm, cm, chunk=chunk)
+    y, s = resolve_op("ssd", impl, grad=grad)(xdt, dta, bm, cm, chunk=chunk)
     y = jnp.swapaxes(y, 1, 2)
     return (y[:, :l_orig] if pad else y), s

@@ -25,14 +25,11 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["ssd_pallas"]
 
 
-def _kernel(xdt_ref, dta_ref, b_ref, c_ref, y_ref, state_out_ref, s_ref, *,
-            n_chunks: int):
+def _kernel(xdt_ref, segc_ref, segr_ref, b_ref, c_ref, y_ref, state_out_ref,
+            s_ref, *, n_chunks: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -40,19 +37,18 @@ def _kernel(xdt_ref, dta_ref, b_ref, c_ref, y_ref, state_out_ref, s_ref, *,
         s_ref[...] = jnp.zeros_like(s_ref)
 
     xdt = xdt_ref[0, 0]            # (Q, P)
-    dta = dta_ref[0, 0]            # (Q,)
+    seg_c = segc_ref[0, 0]         # (Q, 1) inclusive log-decay within chunk
+    seg_r = segr_ref[0, 0]         # (1, Q) the same, as a row
     bm = b_ref[0]                  # (Q, N)
     cm = c_ref[0]                  # (Q, N)
-
-    seg = jnp.cumsum(dta)          # (Q,) inclusive log-decay within chunk
-    total = seg[-1]
+    q = seg_c.shape[0]
+    total = seg_c[q - 1:, :]       # (1, 1)
 
     # intra-chunk: y_i += sum_{j<=i} exp(seg_i - seg_j) * (C_i . B_j) xdt_j
-    q = seg.shape[0]
     li = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     # mask inside the exp (upper triangle overflows otherwise)
-    decay = jnp.exp(jnp.where(li >= lj, seg[:, None] - seg[None, :], -1e30))
+    decay = jnp.exp(jnp.where(li >= lj, seg_c - seg_r, -1e30))
     cb = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (Q, Q)
     y = jnp.dot((cb * decay).astype(xdt.dtype), xdt,
@@ -60,14 +56,16 @@ def _kernel(xdt_ref, dta_ref, b_ref, c_ref, y_ref, state_out_ref, s_ref, *,
 
     # inter-chunk: y_i += exp(seg_i) * C_i @ S_prev^T
     s_prev = s_ref[...]                                           # (P, N)
-    y += jnp.exp(seg)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(seg_c) * jax.lax.dot_general(
         cm, s_prev, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                       # (Q, P)
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # state update: S = exp(total) S_prev + sum_j exp(total - seg_j) xdt_j^T B_j
-    w = jnp.exp(total - seg)[:, None] * xdt                       # (Q, P)
-    s_new = jnp.exp(total) * s_prev + jax.lax.dot_general(
+    w = jnp.exp(total - seg_c) * xdt                              # (Q, P)
+    # the row copy's last lane broadcasts along lanes, then sublanes
+    carry = jnp.broadcast_to(jnp.exp(seg_r[:, q - 1:]), (1, s_prev.shape[1]))
+    s_new = carry * s_prev + jax.lax.dot_general(
         w, bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                       # (P, N)
     s_ref[...] = s_new
@@ -84,13 +82,18 @@ def ssd_pallas(xdt: jax.Array, dta: jax.Array, bm: jax.Array, cm: jax.Array,
     _, _, n = bm.shape
     assert l % chunk == 0, (l, chunk)
     nc = l // chunk
+    # the chunk-local cumulative log-decay is one XLA pass here: Mosaic
+    # lowers no cumsum, and a (1, chunk) block of the (B, H, L) log-decays
+    # does not tile, while a column and a row of the 4-D views do
+    seg = jnp.cumsum(dta.reshape(b, h, nc, chunk), axis=-1).reshape(b, h, l)
     kernel = functools.partial(_kernel, n_chunks=nc)
     y, state = pl.pallas_call(
         kernel,
         grid=(b, h, nc),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p), lambda bb, hh, c: (bb, hh, c, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bb, hh, c: (bb, hh, c)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda bb, hh, c: (bb, hh, c, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda bb, hh, c: (bb, hh, 0, c)),
             pl.BlockSpec((1, chunk, n), lambda bb, hh, c: (bb, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda bb, hh, c: (bb, c, 0)),
         ],
@@ -103,8 +106,8 @@ def ssd_pallas(xdt: jax.Array, dta: jax.Array, bm: jax.Array, cm: jax.Array,
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xdt, dta, bm, cm)
+    )(xdt, seg[..., None], seg[:, :, None, :], bm, cm)
     return y, state

@@ -12,6 +12,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import kernel_block
+
 from .tiled_mm import tiled_mm_pallas
 from .ref import tiled_mm_ref
 
@@ -33,11 +35,11 @@ def tiled_matmul(a: jax.Array, b: jax.Array, *,
                  tile: tuple[int, int, int] | int = (256, 256, 256),
                  out_dtype=None,
                  interpret: bool = False) -> jax.Array:
-    """act(A @ B + bias) for arbitrary (m, k) x (k, n): pads to tile
-    multiples (the fixed-size PE's zero-padded border jobs) and slices the
-    valid region back out."""
-    if isinstance(tile, int):
-        tile = (tile, tile, tile)
+    """act(A @ B + bias) for arbitrary (m, k) x (k, n): runs the job tile
+    as its :func:`~repro.kernels.kernel_block`, pads to block multiples
+    (the fixed-size PE's zero-padded border jobs) and slices the valid
+    region back out."""
+    tile = kernel_block(tile)
     m, k = a.shape
     _, n = b.shape
     ts_m, ts_n, ts_k = tile

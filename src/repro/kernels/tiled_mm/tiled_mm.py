@@ -35,9 +35,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["tiled_mm_pallas"]
 
 
@@ -97,7 +94,7 @@ def tiled_mm_pallas(a: jax.Array, b: jax.Array, *,
         out_specs=pl.BlockSpec((ts_m, ts_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((ts_m, ts_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(flops=flops,
                                       bytes_accessed=bytes_accessed,

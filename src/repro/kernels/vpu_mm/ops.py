@@ -12,6 +12,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import kernel_block
+
 from .vpu_mm import vpu_mm_pallas
 
 __all__ = ["vpu_matmul"]
@@ -33,10 +35,10 @@ def vpu_matmul(a: jax.Array, b: jax.Array, *,
                out_dtype=None,
                interpret: bool = False) -> jax.Array:
     """act(A @ B + bias) for arbitrary (m, k) x (k, n) on the VPU only:
-    pads to tile multiples (the fixed-size PE's zero-padded border jobs)
-    and slices the valid region back out."""
-    if isinstance(tile, int):
-        tile = (tile, tile, tile)
+    runs the job tile as its :func:`~repro.kernels.kernel_block`, pads to
+    block multiples (the fixed-size PE's zero-padded border jobs) and
+    slices the valid region back out."""
+    tile = kernel_block(tile)
     m, k = a.shape
     _, n = b.shape
     ts_m, ts_n, ts_k = tile

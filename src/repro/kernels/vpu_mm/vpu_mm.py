@@ -25,9 +25,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["vpu_mm_pallas"]
 
 
@@ -40,13 +37,11 @@ def _kernel(a_ref, b_ref, bias_ref, o_ref, acc_ref, *,
 
     a = a_ref[...].astype(jnp.float32)           # (ts_m, ts_k)
     b = b_ref[...].astype(jnp.float32)           # (ts_k, ts_n)
-
-    def body(kk, acc):
-        a_col = jax.lax.dynamic_slice_in_dim(a, kk, 1, axis=1)  # (ts_m, 1)
-        b_row = jax.lax.dynamic_slice_in_dim(b, kk, 1, axis=0)  # (1, ts_n)
-        return acc + a_col * b_row               # VPU broadcast FMA
-
-    acc_ref[...] = jax.lax.fori_loop(0, ts_k, body, acc_ref[...])
+    # statically unrolled: Mosaic cannot slice a lane at a dynamic index
+    acc = acc_ref[...]
+    for kk in range(ts_k):
+        acc = acc + a[:, kk:kk + 1] * b[kk:kk + 1, :]   # VPU broadcast FMA
+    acc_ref[...] = acc
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _epilogue():
@@ -95,7 +90,7 @@ def vpu_mm_pallas(a: jax.Array, b: jax.Array, *,
         out_specs=pl.BlockSpec((ts_m, ts_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((ts_m, ts_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(flops=flops,
                                       bytes_accessed=bytes_accessed,

@@ -55,9 +55,7 @@ def build_pp_forward(cfg: ArchConfig, mesh, *, stage_axis: str = "pod",
         return gpipe_spmd(stage_fn, my_blocks, mbs,
                           axis_name=stage_axis, num_stages=num_stages)
 
-    shard = jax.shard_map if hasattr(jax, "shard_map") else None
-    if shard is None:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as shard
-    f = shard(pipelined, mesh=mesh,
-              in_specs=(P(stage_axis), P()), out_specs=P(stage_axis))
+    f = jax.shard_map(pipelined, mesh=mesh,
+                      in_specs=(P(stage_axis), P()),
+                      out_specs=P(stage_axis))
     return jax.jit(f), num_stages

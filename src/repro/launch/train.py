@@ -21,8 +21,8 @@ from repro.optim import (AdamWConfig, AdafactorConfig, adamw_init,
                          adamw_update, adafactor_init, adafactor_update)
 from .sharding import input_pspecs, state_pspecs, to_shardings
 
-__all__ = ["make_train_state", "build_train_step", "train_loop",
-           "train_state_specs"]
+__all__ = ["make_train_state", "init_train_state", "build_train_step",
+           "train_loop", "train_state_specs"]
 
 
 def make_train_state(cfg: ArchConfig, key, opt_cfg=None) -> dict:
@@ -37,6 +37,14 @@ def make_train_state(cfg: ArchConfig, key, opt_cfg=None) -> dict:
 def train_state_specs(cfg: ArchConfig, mesh):
     aval = jax.eval_shape(lambda: make_train_state(cfg, jax.random.key(0)))
     return aval, state_pspecs(cfg, aval, mesh)
+
+
+def init_train_state(cfg: ArchConfig, mesh, key) -> dict:
+    """The train state built by one program directly in its shardings on
+    ``mesh``: no device ever holds the whole state on the way."""
+    _, sspecs = train_state_specs(cfg, mesh)
+    return jax.jit(lambda k: make_train_state(cfg, k),
+                   out_shardings=to_shardings(sspecs, mesh))(key)
 
 
 def _train_step(cfg: ArchConfig, opt_cfg, state: dict, batch: dict):
@@ -85,8 +93,7 @@ def train_loop(cfg: ArchConfig, mesh, *, steps: int, batch_iter,
     jfn, (aval, sspecs), _ = build_train_step(cfg, cell, mesh,
                                               opt_cfg=opt_cfg)
     if state is None:
-        with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
-            state = make_train_state(cfg, key)
+        state = init_train_state(cfg, mesh, key)
     history = []
     for _ in range(steps):
         batch = next(batch_iter)

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.synergy_mm import synergy_matmul
-from repro.engines import register_op_impl, resolve_op
+from repro.engines import register_op_impl, resolve_op, under_grad_trace
 from repro.kernels.flash_attention import attention_ref, flash_attention
 from .layers import init_dense, rope
 
@@ -33,16 +33,10 @@ _NEG = -1e30
 
 def _match_vma(init: jax.Array, *refs: jax.Array) -> jax.Array:
     """Give scan-carry initializers the union of the refs' varying manual
-    axes (shard_map contexts); no-op outside shard_map or on older jax."""
-    try:
-        vma: set = set()
-        for r in refs:
-            vma |= set(getattr(jax.typeof(r), "vma", ()) or ())
-        pcast = getattr(jax.lax, "pcast", None)
-        if vma and pcast is not None:
-            return pcast(init, tuple(sorted(vma)), to="varying")
-    except Exception:
-        pass
+    axes (shard_map contexts); no-op outside shard_map."""
+    vma = frozenset().union(*(jax.typeof(r).vma for r in refs))
+    if vma:
+        return jax.lax.pcast(init, tuple(sorted(vma)), to="varying")
     return init
 
 
@@ -159,7 +153,8 @@ register_op_impl(
     "attention_scores", "pallas",
     lambda q, k, v, *, causal, blk_q, blk_k: flash_attention(
         q, k, v, causal=causal, impl="pallas"),
-    priority=10, available=lambda: jax.default_backend() == "tpu")
+    priority=10, available=lambda: jax.default_backend() == "tpu",
+    grad=False)
 register_op_impl(
     "attention_scores", "flash_xla",
     lambda q, k, v, *, causal, blk_q, blk_k: flash_attention_xla(
@@ -173,8 +168,9 @@ register_op_impl(
 
 
 def _scores_engine(q, k, v, *, causal, impl, blk_q=512, blk_k=1024):
-    return resolve_op("attention_scores", impl)(q, k, v, causal=causal,
-                                                blk_q=blk_q, blk_k=blk_k)
+    fn = resolve_op("attention_scores", impl,
+                    grad=under_grad_trace(q, k, v))
+    return fn(q, k, v, causal=causal, blk_q=blk_q, blk_k=blk_k)
 
 
 def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,

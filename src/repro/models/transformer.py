@@ -17,6 +17,7 @@ cfg.remat.  Decode threads per-layer caches through the same scan.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Optional
 
 import jax
@@ -148,27 +149,17 @@ def _constrain_batch(x: jax.Array) -> jax.Array:
     """§Perf iteration: pin the residual stream to batch-sharding over the
     data axes.  Without this the partitioner flip-flops (e.g. internlm2
     prefill ran its MLP batch-REPLICATED, paying a 4.3 GB collective-permute
-    3x per layer).  No-op outside a mesh context (CPU unit tests)."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        names = getattr(mesh, "axis_names", ())
-        if not names or "model" not in names:
-            return x
-        dp = tuple(a for a in names if a != "model")
-        if x.shape[0] % _mesh_size(mesh, dp):
-            return x
-        from jax.sharding import PartitionSpec as P
-        return jax.lax.with_sharding_constraint(
-            x, P(dp, *([None] * (x.ndim - 1))))
-    except Exception:
+    3x per layer).  No-op outside a ``jax.set_mesh`` context with a model
+    axis, and where the batch does not divide the data axes."""
+    from jax.sharding import PartitionSpec as P
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return x
-
-
-def _mesh_size(mesh, axes) -> int:
-    total = 1
-    for a in axes:
-        total *= dict(zip(mesh.axis_names, mesh.axis_sizes))[a]
-    return total
+    dp = tuple(a for a in mesh.axis_names if a != "model")
+    if x.shape[0] % math.prod(mesh.shape[a] for a in dp):
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, P(dp, *([None] * (x.ndim - 1))))
 
 
 def _scan_blocks(body, x, stacked, remat: bool):

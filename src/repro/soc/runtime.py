@@ -1496,8 +1496,7 @@ class SynergyRuntime:
         activations and weights ONCE for the whole split.  Returns
         ``(qw, act_scale, a_q)`` or None (no quantized engine in the
         pool, shape still warming up, or trace-time Tracers)."""
-        tracer = getattr(jax.core, "Tracer", ())
-        if isinstance(a, tracer) or isinstance(b, tracer):
+        if not is_concrete(a, b):
             return None
         with self._lock:
             engs = [w.engine for w in self._workers.values()]
@@ -1675,5 +1674,4 @@ def is_concrete(*arrays) -> bool:
     """Runtime splitting needs concrete arrays (worker threads cannot share
     another thread's JAX trace); under jit we fall back to single-engine
     dispatch."""
-    tracer = getattr(jax.core, "Tracer", ())
-    return not any(isinstance(x, tracer) for x in arrays)
+    return not any(isinstance(x, jax.core.Tracer) for x in arrays)

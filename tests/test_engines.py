@@ -202,6 +202,54 @@ def test_resolve_op_variants():
         resolve_op("no_such_op")
 
 
+@pytest.mark.parametrize("arch,op", [("granite-3-2b", "attention_scores"),
+                                     ("mamba2-130m", "ssd")])
+def test_training_skips_variants_without_vjp(arch, op):
+    """The Pallas variants have no VJP.  Made available (as on a TPU),
+    auto resolution must pick them for inference and skip them wherever
+    the train step differentiates the op (jit + remat + scan)."""
+    from repro.configs import ARCHS, reduced
+    from repro.engines import op_variants, register_op_impl
+    from repro.models import init_model, lm_loss
+    saved = {v.name: v for v in op_variants(op)}["pallas"]
+    fallback = {v.name: v for v in op_variants(op)}[
+        "flash_xla" if op == "attention_scores" else "xla"].fn
+    calls = []
+
+    def probe(*args, **kw):
+        calls.append(1)
+        return fallback(*args, **kw)
+
+    register_op_impl(op, "pallas", probe, priority=saved.priority,
+                     grad=False, override=True)
+    try:
+        cfg = reduced(ARCHS[arch])
+        assert cfg.remat
+        params = init_model(cfg, jax.random.key(0))
+        batch = {"tokens": jnp.zeros((2, 16), jnp.int32),
+                 "labels": jnp.ones((2, 16), jnp.int32)}
+        loss = lambda p: lm_loss(cfg, p, batch)
+        jax.jit(loss)(params)
+        assert calls, "auto resolution skipped an available variant"
+        n = len(calls)
+        grads = jax.jit(jax.grad(loss))(params)
+        assert len(calls) == n
+        assert all(bool(jnp.all(jnp.isfinite(g)))
+                   for g in jax.tree.leaves(grads))
+    finally:
+        register_op_impl(op, "pallas", saved.fn, priority=saved.priority,
+                         available=saved.available, grad=saved.grad,
+                         override=True)
+
+
+@pytest.mark.parametrize("tile,block", [
+    (32, (32, 128, 128)), ((256, 256, 256), (256, 256, 256)),
+    ((8, 16, 200), (32, 128, 256)), ((40, 130, 128), (64, 256, 128))])
+def test_kernel_block_is_tpu_tileable(tile, block):
+    from repro.kernels import kernel_block
+    assert kernel_block(tile) == block
+
+
 # ------------------------------------------------- scheduler/registry view
 
 def test_accelerators_are_registry_views():
