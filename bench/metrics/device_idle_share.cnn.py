@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device
+(1 - union of device-op intervals / window).  Moves
+``frames_per_s``."""
+
+
+def read(ctx):
+    tr = ctx.get("trace")
+    if not tr or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
