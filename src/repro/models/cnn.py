@@ -24,6 +24,7 @@ from repro.core.im2col import conv2d_gemm, conv_out_shape, im2col
 from repro.core.job import JobSet
 from repro.core.scheduler import SimLayer, SimNet
 from repro.core.synergy_mm import synergy_matmul
+from repro.obs.trace import annotate
 
 __all__ = ["CNNConfig", "init_cnn", "cnn_forward", "build_simnet",
            "conv_jobsets", "conv_graph_steps", "conv_wave_graph",
@@ -101,7 +102,9 @@ def _conv_via_jobs(x, w, b, stride, pad, tile, name, engine=None,
     kh, kw, cin, cout = w.shape
     n, h, wd, _ = x.shape
     oh, ow = conv_out_shape(h, wd, kh, kw, stride, pad)
-    a = im2col(x, kh, kw, stride, pad).reshape(n * oh * ow, kh * kw * cin)
+    with annotate("repro/cnn/im2col"):
+        a = im2col(x, kh, kw, stride, pad).reshape(n * oh * ow,
+                                                   kh * kw * cin)
     y = synergy_matmul(a, w.reshape(-1, cout), bias=b,
                        activation=jax.nn.relu, tile=tile, name=name,
                        engine=engine, job_class=job_class)
@@ -130,7 +133,7 @@ def cnn_forward(cfg: CNNConfig, params: dict, x: jax.Array, *,
         scope = runtime_scope(runtime)
     else:
         scope = contextlib.nullcontext()
-    with scope:
+    with annotate("repro/cnn/forward"), scope:
         return _cnn_forward(cfg, params, x, engine=engine,
                             job_class=job_class)
 
@@ -146,7 +149,8 @@ def _cnn_forward(cfg: CNNConfig, params: dict, x: jax.Array, *,
                                s, p, cfg.tile, f"{cfg.name}/conv{i}",
                                engine=engine, job_class=job_class)
         elif spec[0] == "pool":
-            x = maxpool2d(x, spec[1])
+            with annotate("repro/cnn/pool"):
+                x = maxpool2d(x, spec[1])
         elif spec[0] == "fc":
             n = x.shape[0]
             x = x.reshape(n, -1)

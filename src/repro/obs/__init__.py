@@ -20,14 +20,14 @@ it without cycles.
 from repro.obs.flightrec import FlightRecorder
 from repro.obs.metrics import (MetricsRegistry, REGISTRY, parse_prometheus,
                                render_prometheus)
-from repro.obs.trace import (EVENT_KINDS, TraceEvent, Tracer,
+from repro.obs.trace import (EVENT_KINDS, TraceEvent, Tracer, annotate,
                              get_default_tracer, load_chrome_trace,
                              set_default_tracer, trace_scope,
                              validate_events)
 
 __all__ = [
     "EVENT_KINDS", "FlightRecorder", "MetricsRegistry", "REGISTRY",
-    "TraceEvent", "Tracer", "get_default_tracer", "load_chrome_trace",
-    "parse_prometheus", "render_prometheus", "set_default_tracer",
-    "trace_scope", "validate_events",
+    "TraceEvent", "Tracer", "annotate", "get_default_tracer",
+    "load_chrome_trace", "parse_prometheus", "render_prometheus",
+    "set_default_tracer", "trace_scope", "validate_events",
 ]

@@ -295,6 +295,13 @@ def collect_runtime(rt, registry: MetricsRegistry = REGISTRY) -> None:
         st["total_steals"] / st["total_jobs"] if st["total_jobs"] else 0.0)
     registry.counter("repro_runtime_submissions_total",
                      "jobset submissions").set_total(st["submissions"])
+    registry.counter("repro_runtime_panels_total",
+                     "panel executions (a retry or a steal is one "
+                     "more)").set_total(st.get("total_panels", 0))
+    registry.counter("repro_runtime_queue_wait_seconds_total",
+                     "summed time panels waited in a queue before a "
+                     "worker took them").set_total(
+        st.get("total_queue_wait_s", 0.0))
     registry.counter("repro_runtime_rebalances_total",
                      "hotplug/quarantine queue rebalances").set_total(
         st["rebalances"])

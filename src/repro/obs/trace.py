@@ -19,6 +19,12 @@ Export is Chrome/Perfetto ``trace_event`` JSON: ``panel_start`` /
 other kind becomes an ``"i"`` instant, and ``"M"`` metadata events name
 the per-track rows so the file loads directly in ``chrome://tracing`` or
 https://ui.perfetto.dev.
+
+:func:`annotate` is the other half: it opens a program span in the JAX
+profiler's own host trace (``repro/...`` names), on the clock the
+device's operations are traced on, so a span can be laid over the device
+timeline.  It records only while the profiler runs
+(``jax.profiler.start_trace``); otherwise it costs one object.
 """
 
 from __future__ import annotations
@@ -28,6 +34,8 @@ import json
 import threading
 from contextlib import contextmanager
 from time import perf_counter
+
+from jax.profiler import TraceAnnotation
 
 #: the closed event vocabulary shared by live runtime, graph scheduler,
 #: serving loop, and the virtual-time sim twin
@@ -51,6 +59,14 @@ _SPAN_STARTS = {"panel_start"}
 _SPAN_ENDS = {"panel_end"}
 
 _seq = itertools.count()        # CPython-atomic global ordering tiebreak
+
+
+def annotate(name: str, **tags) -> TraceAnnotation:
+    """The one way the program opens a span in the profiler's trace:
+    ``with annotate("repro/runtime/wait"): ...``.  ``tags`` become the
+    event's metadata.  Starting the profiler is the switch; with it off a
+    span records nothing."""
+    return TraceAnnotation(name, **tags)
 
 
 class TraceEvent:

@@ -46,7 +46,7 @@ import jax
 from repro.engines.base import CAP_GEMM, CAP_INT8, CAP_SIM, Engine
 from repro.engines.dispatch import JOB_CLASSES
 from repro.obs.flightrec import FlightRecorder
-from repro.obs.trace import get_default_tracer
+from repro.obs.trace import annotate, get_default_tracer
 from repro.engines.registry import (add_registry_listener, get_engine,
                                     remove_registry_listener)
 from .faults import (CorruptOutput, DroppedCompletion, PanelRetryExhausted,
@@ -162,11 +162,16 @@ class _RuntimeJob:
     them, and a queue stays sorted non-increasing in priority, so the
     head is always the most urgent panel and the tail the most stealable
     one.  Neutral jobs (priority 0, no deadline) place exactly as the
-    pre-QoS runtime did."""
+    pre-QoS runtime did.
+
+    ``enqueued_at`` is stamped (``time.perf_counter``) when the job enters
+    a queue from outside one, and cleared when a worker takes it, so the
+    taker books the whole wait to ``queue_wait_s``; a rebalance that moves
+    a queued job keeps its stamp."""
 
     __slots__ = ("sub", "index", "fn", "n_jobs", "job_macs", "job_bytes",
                  "stealable", "int8_ok", "priority", "deadline_at",
-                 "attempts", "failed_on")
+                 "attempts", "failed_on", "enqueued_at")
 
     def __init__(self, sub: "_Submission", index: int, fn, n_jobs: int,
                  job_macs: int, job_bytes: int, stealable: bool = True,
@@ -187,6 +192,7 @@ class _RuntimeJob:
         # failure — the fault-free hot path never allocates the list)
         self.attempts = 0
         self.failed_on: Optional[list[str]] = None
+        self.enqueued_at: Optional[float] = None
 
 
 class _Submission:
@@ -235,7 +241,8 @@ class _Submission:
             self.future._finish(None, self.error)
         else:
             try:
-                value = self.merge(self.parts) if self.merge else None
+                with annotate("repro/runtime/merge"):
+                    value = self.merge(self.parts) if self.merge else None
             except BaseException as e:      # merge bug must not hang callers
                 self.future._finish(None, e)
             else:
@@ -253,8 +260,13 @@ class _Worker:
         self.thread: Optional[threading.Thread] = None
         self.stopped = False
         self.idle = False
-        # per-runtime counters (engine.telemetry is process-global)
+        #: the profiler span of this worker's panels, named once
+        self.span = f"repro/panel/{engine.name}"
+        # per-runtime counters (engine.telemetry is process-global);
+        # ``panels`` counts executions (a retry or a steal is one more)
         self.jobs = 0
+        self.panels = 0
+        self.queue_wait_s = 0.0
         self.steals = 0
         self.est_busy_s = 0.0
         self.wall_busy_s = 0.0
@@ -379,8 +391,7 @@ class SynergyRuntime:
         self._workers: dict[str, _Worker] = {}
         self._retired: list[threading.Thread] = []
         #: counters of removed engines, so stats() totals never go backwards
-        self._retired_counters = {"jobs": 0, "steals": 0, "est_busy_s": 0.0,
-                                  "wall_busy_s": 0.0, "idle_s": 0.0}
+        self._retired_counters = self._zero_counters()
         self._started = False
         self._stopping = False
         self._rebalances = 0
@@ -539,6 +550,8 @@ class SynergyRuntime:
             self._retired.append(w.thread)
         c = self._retired_counters
         c["jobs"] += w.jobs
+        c["panels"] += w.panels
+        c["queue_wait_s"] += w.queue_wait_s
         c["steals"] += w.steals
         c["est_busy_s"] += w.est_busy_s
         c["wall_busy_s"] += w.wall_busy_s
@@ -640,6 +653,7 @@ class SynergyRuntime:
                         default=0.0)
         avoid_on = (self._retry is not None
                     and self._retry.avoid_failed_engine)
+        now = time.perf_counter()
         for job in self._seed_order(jobs, best_rate):
             elig = [i for i in range(len(workers))
                     if job.int8_ok or not is_int8[i]]
@@ -671,6 +685,8 @@ class SynergyRuntime:
                 ai = lpt_pick(idxs, loads, costs)
             loads[ai] += (workers[ai].job_time(job.job_macs, job.job_bytes)
                           * job.n_jobs)
+            if job.enqueued_at is None:
+                job.enqueued_at = now
             self._enqueue(workers[ai].queue, job)
             if tr is not None:
                 tr.emit("enqueue", workers[ai].engine.name,
@@ -765,8 +781,12 @@ class SynergyRuntime:
                         w.idle_s += dt
                         w.engine.telemetry.record_runtime(idle_s=dt)
                 w.idle = False
+                w.panels += 1
+                w.queue_wait_s += time.perf_counter() - job.enqueued_at
+                job.enqueued_at = None
             try:
-                self._execute(w, job, stolen)
+                with annotate(w.span):
+                    self._execute(w, job, stolen)
             except WorkerKilled:
                 # injected mid-panel death: the thread exits without
                 # completing its panel (the live-panel registry entry
@@ -788,7 +808,9 @@ class SynergyRuntime:
                 # block on async dispatch: an unrealized jax.Array returns
                 # in ~µs and would make the measured (recalibration) rate
                 # orders of magnitude too high on real backends
-                part = jax.block_until_ready(job.fn(eng))
+                out = job.fn(eng)
+                with annotate("repro/device_wait"):
+                    part = jax.block_until_ready(out)
         except WorkerKilled:
             # mid-panel worker death: re-raise WITHOUT completing and
             # WITHOUT clearing the live-panel entry — the monitor reads
@@ -1419,74 +1441,77 @@ class SynergyRuntime:
         must not have every sub-submission fold an extra EMA update, or
         batched and per-slot decode would calibrate — and therefore
         quantize — differently."""
-        import jax.numpy as jnp
-        ts_m = jobset.ts_m
-        m = a.shape[0]
-        gm, gn = jobset.grid
-        j = next(jobset.jobs())
-        final_dtype = out_dtype or a.dtype
-        int8_ok = _admits_int8(job_class)
+        with annotate("repro/runtime/submit"):
+            import jax.numpy as jnp
+            ts_m = jobset.ts_m
+            m = a.shape[0]
+            gm, gn = jobset.grid
+            j = next(jobset.jobs())
+            final_dtype = out_dtype or a.dtype
+            int8_ok = _admits_int8(job_class)
 
-        plan = (self._plan_int8_split(a, b, observe=observe_acts)
-                if int8_ok else None)
-        if plan is not None:
-            qw, act_scale, a_q = plan
-            tile_t = tile if isinstance(tile, tuple) else (tile,) * 3
+            plan = (self._plan_int8_split(a, b, observe=observe_acts)
+                    if int8_ok else None)
+            if plan is not None:
+                qw, act_scale, a_q = plan
+                tile_t = tile if isinstance(tile, tuple) else (tile,) * 3
 
-            def make_qfn(r0: int, r1: int):
+                def make_qfn(r0: int, r1: int):
+                    def fn(eng: Engine):
+                        fn8 = getattr(eng, "execute_int8", None)
+                        if fn8 is not None:
+                            return fn8(a_q[r0:r1], qw, tile=tile_t)
+                        # any engine can compute the exact integer partial
+                        # through the shared kernel (steals/hotplug-safe)
+                        from repro.kernels.qmm import qmm_matmul
+                        return qmm_matmul(a_q[r0:r1], qw.q, qw.scale,
+                                          fuse_dequant=False, tile=tile_t)
+                    return fn
+
+                units = [(make_qfn(t1 * ts_m, min((t1 + 1) * ts_m, m)),
+                          gn, j.macs, j.bytes_moved) for t1 in range(gm)]
+
+                def merge_q(parts: list):
+                    from repro.quant.quantize import dequant_finish
+                    acc = (parts[0] if len(parts) == 1
+                           else jnp.concatenate(parts, 0))
+                    return dequant_finish(acc, qw, act_scale=act_scale,
+                                          bias=bias, activation=activation,
+                                          out_dtype=final_dtype)
+
+                return self._submit_jobs(jobset, units, merge_q, affinity,
+                                         stealable=True, int8_ok=True,
+                                         qos=qos)
+
+            def make_fn(r0: int, r1: int):
                 def fn(eng: Engine):
-                    fn8 = getattr(eng, "execute_int8", None)
-                    if fn8 is not None:
-                        return fn8(a_q[r0:r1], qw, tile=tile_t)
-                    # any engine can compute the exact integer partial
-                    # through the shared kernel (steals/hotplug-safe)
-                    from repro.kernels.qmm import qmm_matmul
-                    return qmm_matmul(a_q[r0:r1], qw.q, qw.scale,
-                                      fuse_dequant=False, tile=tile_t)
+                    ex = getattr(eng, "execute_weight_only", eng.execute)
+                    return ex(a[r0:r1], b, bias=bias,
+                              activation=activation, tile=tile,
+                              out_dtype=jnp.float32,
+                              precision=precision)
                 return fn
 
-            units = [(make_qfn(t1 * ts_m, min((t1 + 1) * ts_m, m)),
-                      gn, j.macs, j.bytes_moved) for t1 in range(gm)]
+            units = []
+            for t1 in range(gm):
+                r0, r1 = t1 * ts_m, min((t1 + 1) * ts_m, m)
+                units.append((make_fn(r0, r1), gn, j.macs, j.bytes_moved))
 
-            def merge_q(parts: list):
-                from repro.quant.quantize import dequant_finish
-                acc = (parts[0] if len(parts) == 1
-                       else jnp.concatenate(parts, 0))
-                return dequant_finish(acc, qw, act_scale=act_scale,
-                                      bias=bias, activation=activation,
-                                      out_dtype=final_dtype)
+            def merge(parts: list):
+                y = (parts[0] if len(parts) == 1
+                     else jnp.concatenate(parts, 0))
+                return y.astype(final_dtype)
 
-            return self._submit_jobs(jobset, units, merge_q, affinity,
-                                     stealable=True, int8_ok=True, qos=qos)
-
-        def make_fn(r0: int, r1: int):
-            def fn(eng: Engine):
-                ex = getattr(eng, "execute_weight_only", eng.execute)
-                return ex(a[r0:r1], b, bias=bias,
-                          activation=activation, tile=tile,
-                          out_dtype=jnp.float32,
-                          precision=precision)
-            return fn
-
-        units = []
-        for t1 in range(gm):
-            r0, r1 = t1 * ts_m, min((t1 + 1) * ts_m, m)
-            units.append((make_fn(r0, r1), gn, j.macs, j.bytes_moved))
-
-        def merge(parts: list):
-            y = parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
-            return y.astype(final_dtype)
-
-        # the mixed check and the enqueue must be one atomic step: a
-        # hotplug between them would enqueue stealable panels into a
-        # now-mixed pool and break the determinism pin (the Condition's
-        # underlying RLock makes the nested acquire in _submit_jobs safe)
-        with self._cond:
-            mixed = self._mixed_precision_pool()
-            return self._submit_jobs(jobset, units, merge,
-                                     None if mixed else affinity,
-                                     stealable=not mixed, int8_ok=int8_ok,
-                                     qos=qos)
+            # the mixed check and the enqueue must be one atomic step: a
+            # hotplug between them would enqueue stealable panels into a
+            # now-mixed pool and break the determinism pin (the Condition's
+            # underlying RLock makes the nested acquire in _submit_jobs safe)
+            with self._cond:
+                mixed = self._mixed_precision_pool()
+                return self._submit_jobs(jobset, units, merge,
+                                         None if mixed else affinity,
+                                         stealable=not mixed,
+                                         int8_ok=int8_ok, qos=qos)
 
     def _plan_int8_split(self, a, b, observe: bool = True):
         """Plan the shared quantization of an opted-in GEMM: observe the
@@ -1537,7 +1562,9 @@ class SynergyRuntime:
                                out_dtype=out_dtype, precision=precision,
                                affinity=affinity, job_class=job_class,
                                qos=qos)
-        return fut.result(timeout), fut.accounting
+        with annotate("repro/runtime/wait"):
+            out = fut.result(timeout)
+        return out, fut.accounting
 
     # ----------------------------------------------------- recalibration
     def recalibrate(self, alpha: float = 0.5, *,
@@ -1578,7 +1605,8 @@ class SynergyRuntime:
             for name, w in self._workers.items():
                 denom = w.wall_busy_s + w.idle_s
                 per[name] = {
-                    "jobs": w.jobs, "steals": w.steals,
+                    "jobs": w.jobs, "panels": w.panels,
+                    "queue_wait_s": w.queue_wait_s, "steals": w.steals,
                     "est_busy_s": w.est_busy_s,
                     "wall_busy_s": w.wall_busy_s, "idle_s": w.idle_s,
                     "busy_fraction": w.wall_busy_s / denom if denom else 0.0,
@@ -1606,6 +1634,11 @@ class SynergyRuntime:
                 # never makes the counters go backwards
                 "total_jobs": sum(p["jobs"] for p in per.values())
                 + retired["jobs"],
+                "total_panels": sum(p["panels"] for p in per.values())
+                + retired["panels"],
+                "total_queue_wait_s": sum(p["queue_wait_s"]
+                                          for p in per.values())
+                + retired["queue_wait_s"],
                 "total_steals": sum(p["steals"] for p in per.values())
                 + retired["steals"],
                 # Table-6 analog on the cost-model basis: total busy over
@@ -1613,11 +1646,18 @@ class SynergyRuntime:
                 "aggregate_busy_fraction": agg,
             }
 
+    @staticmethod
+    def _zero_counters() -> dict:
+        return {"jobs": 0, "panels": 0, "queue_wait_s": 0.0, "steals": 0,
+                "est_busy_s": 0.0, "wall_busy_s": 0.0, "idle_s": 0.0}
+
     def reset_stats(self) -> None:
         with self._lock:
             for w in self._workers.values():
-                w.jobs = w.steals = 0
+                w.jobs = w.panels = w.steals = 0
+                w.queue_wait_s = 0.0
                 w.est_busy_s = w.wall_busy_s = w.idle_s = 0.0
+            self._retired_counters = self._zero_counters()
             self._submissions = 0
             self._rebalances = 0
             self._quarantines = 0

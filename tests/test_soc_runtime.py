@@ -420,3 +420,111 @@ def test_simruntime_no_affinity_and_empty_jobset():
     empty = JobSet.for_gemm(0, 0, 0, 0, 32)
     res0 = SimRuntime(["F-PE"]).run(empty)
     assert res0.makespan_s == 0.0 and res0.total_steals == 0
+
+
+# ------------------------------------ panel and queue-wait counters
+
+def _panel_counters_hold(st):
+    """Per-engine panels and queue waits add up to the totals."""
+    per = st["engines"].values()
+    assert st["total_panels"] == sum(p["panels"] for p in per) \
+        + st["retired"]["panels"]
+    assert st["total_queue_wait_s"] == pytest.approx(
+        sum(p["queue_wait_s"] for p in per)
+        + st["retired"]["queue_wait_s"])
+    assert st["total_queue_wait_s"] >= 0.0
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_panels_and_queue_wait_count_every_execution_under_steals(seed):
+    engines = [_DelayEngine(f"q{i}", macs_per_s=(i + 1) * 1e9,
+                            seed=seed * 10 + i) for i in range(3)]
+    a, b = _ab(20 * 16, 32, 16, seed=seed)
+    js = JobSet.for_gemm(0, a.shape[0], 16, 32, 16)
+    with SynergyRuntime(engines) as rt:
+        rt.submit_gemm(a, b, jobset=js, tile=(16, 16, 16),
+                       affinity="q0").result(60)
+        st = rt.stats()
+    # a steal is one execution, booked once, by the thief
+    assert st["total_panels"] == 20 == sum(e.executed for e in engines)
+    assert st["total_steals"] <= st["total_panels"]
+    assert st["total_queue_wait_s"] > 0.0
+    _panel_counters_hold(st)
+
+
+def test_panels_count_a_retried_panel_twice():
+    from repro.soc import FaultPlan, FaultSpec, RetryPolicy, wrap_pool
+    engines = [_DelayEngine(f"f{i}", seed=i, max_delay_s=0.001)
+               for i in range(2)]
+    plan = FaultPlan((FaultSpec("f0", "raise", at_call=0),), seed=0)
+    a, b = _ab(8 * 16, 32, 16, seed=5)
+    js = JobSet.for_gemm(0, a.shape[0], 16, 32, 16)
+    with SynergyRuntime(wrap_pool(engines, plan),
+                        retry=RetryPolicy(max_attempts=3)) as rt:
+        y = rt.submit_gemm(a, b, jobset=js, tile=(16, 16, 16),
+                           affinity="f0").result(60)
+        st = rt.stats()
+    assert plan.injected == [("f0", "raise", 0)]
+    assert st["retries"] == 1
+    assert st["total_panels"] == 8 + st["retries"]
+    _panel_counters_hold(st)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(jnp.dot(a, b)),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_panel_totals_survive_hot_unplug_and_reset_clears_them():
+    doomed = _DelayEngine("u0", seed=31, max_delay_s=0.01)
+    survivor = _DelayEngine("u1", seed=32, max_delay_s=0.0)
+    a, b = _ab(24 * 16, 32, 16, seed=9)
+    js = JobSet.for_gemm(0, a.shape[0], 16, 32, 16)
+    with SynergyRuntime([doomed, survivor]) as rt:
+        fut = rt.submit_gemm(a, b, jobset=js, tile=(16, 16, 16),
+                             affinity="u0")
+        before = rt.stats()
+        rt.remove_engine("u0")
+        during = rt.stats()
+        fut.result(120)
+        after = rt.stats()
+        assert before["total_panels"] <= during["total_panels"] \
+            <= after["total_panels"] == 24
+        assert before["total_queue_wait_s"] <= during["total_queue_wait_s"] \
+            <= after["total_queue_wait_s"]
+        assert "u0" not in after["engines"]
+        _panel_counters_hold(after)
+        rt.reset_stats()
+        st = rt.stats()
+    assert st["total_panels"] == 0 and st["total_queue_wait_s"] == 0.0
+    assert st["total_jobs"] == 0
+
+
+def test_prometheus_shows_panel_and_queue_wait_totals():
+    from repro.obs.metrics import (MetricsRegistry, parse_prometheus,
+                                   render_prometheus)
+    a, b = _ab(6 * 16, 32, 16, seed=2)
+    js = JobSet.for_gemm(0, a.shape[0], 16, 32, 16)
+    with SynergyRuntime([_DelayEngine("m0"), _DelayEngine("m1")]) as rt:
+        rt.submit_gemm(a, b, jobset=js, tile=(16, 16, 16)).result(60)
+        st = rt.stats()
+        got = parse_prometheus(render_prometheus(runtime=rt,
+                                                 registry=MetricsRegistry()))
+    assert got["repro_runtime_panels_total"] == [({}, 6.0)]
+    [(labels, wait)] = got["repro_runtime_queue_wait_seconds_total"]
+    assert labels == {} and wait == pytest.approx(st["total_queue_wait_s"])
+
+
+def test_cnn_forward_bitwise_equal_with_profiler_on_and_off(tmp_path):
+    from repro.models.cnn import CNNConfig, cnn_forward, init_cnn
+    net = CNNConfig(name="tiny", input_hw=8, cin=1, tile=8, layers=(
+        ("conv", 4, 3, 1, 1), ("pool", 2), ("conv", 8, 3, 1, 1),
+        ("fc", 10)))
+    params = init_cnn(net, jax.random.key(0))
+    x = jax.random.normal(jax.random.key(1), (4, 8, 8, 1))
+    with SynergyRuntime([_DelayEngine("p0", max_delay_s=0.0),
+                         _DelayEngine("p1", max_delay_s=0.0)]) as rt:
+        off = np.asarray(cnn_forward(net, params, x, runtime=rt))
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            on = np.asarray(cnn_forward(net, params, x, runtime=rt))
+        finally:
+            jax.profiler.stop_trace()
+    assert np.array_equal(on, off)
