@@ -11,9 +11,11 @@ discrete-event simulator applies).
 Execution model
 ---------------
 A *submission* is one JobSet plus its executable decomposition.  For a real
-GEMM the unit of scheduling is a **row panel** — one grid row of the
-paper's (t1, t2) tile jobs; every tile job belongs to exactly one panel, so
-panels steal freely while the merge stays a concatenation (no cross-engine
+GEMM the unit of scheduling is a **row panel** — a run of consecutive grid
+rows of the paper's (t1, t2) tile jobs, sized by :func:`row_panels` so
+that each panel carries at least the work its fixed host cost buys on the
+device; every tile job belongs to exactly one panel, so panels steal
+freely while the merge stays a concatenation (no cross-engine
 accumulation).  Accounting-only submissions (serving prefill/decode
 proxies) schedule at single tile-job granularity.
 
@@ -57,11 +59,49 @@ from .qos_policy import (NEUTRAL_TAG, QosTag, effective_deadline,
                          qos_victim, queue_insert_index)
 
 __all__ = ["SynergyRuntime", "RuntimeFuture", "RetryPolicy",
-           "runtime_scope", "current_runtime"]
+           "runtime_scope", "current_runtime", "row_panels",
+           "PANEL_FLOOR_MACS"]
 
 #: idle-book wait quantum.  Wakeups are notify-driven (submit / pool change
 #: / shutdown all notify_all); the timeout is only a lost-wakeup backstop.
 _IDLE_WAIT_S = 0.5
+
+#: The least work, in MACs, that one dispatched GEMM panel carries, by JAX
+#: backend.  Whatever its size, a panel costs a worker a fixed host time
+#: (its row slice, the engine call, the wait on its result, its part of
+#: the merge) that buys no device time, and every engine of a TPU pool is
+#: a program on the same TensorCore, so a split buys host overlap only.
+#: TPU v5e: traced CIFAR_full runs of 64-frame calls at one tile row per
+#: panel retired 32,280 panels in a 32.49 s window and 29,590 in 30.22 s,
+#: the device idle 97% of each: 1.0 ms of host time per panel over four
+#: workers sharing the interpreter lock.  1.0e-3 s x 60e12 MAC/s
+#: (XlaEngine's TPU rate) = 6e10 MACs.  No other backend has a measured per-panel cost: 0, one
+#: panel per tile row.
+PANEL_FLOOR_MACS = {"tpu": 6e10}
+
+
+def row_panels(m: int, k: int, n: int, ts_m: int,
+               backend: Optional[str] = None) -> list[tuple[int, int]]:
+    """The row ranges ``[(r0, r1), ...]`` that ``submit_gemm`` dispatches
+    as panels of an (m, k) @ (k, n) GEMM with ``ts_m``-row tiles.  A panel
+    holds the smallest power of two of tile rows whose MACs (rows x k x n)
+    reach the backend's :data:`PANEL_FLOOR_MACS`, at most the whole GEMM;
+    only the last panel may be ragged.  A function of the shape and the
+    backend (default: JAX's) alone, so pool membership, recalibrated rates
+    and timing never change the panels."""
+    floor = PANEL_FLOOR_MACS.get(backend or jax.default_backend(), 0)
+    gm = -(-m // ts_m)
+    t = 1
+    while t < gm and t * ts_m * k * n < floor:
+        t *= 2
+    rows = t * ts_m
+    return [(r0, min(r0 + rows, m)) for r0 in range(0, m, rows)]
+
+
+def _rows(x, r0: int, r1: int):
+    """Rows [r0, r1) of ``x``; ``x`` itself when that is all of it, so a
+    one-panel GEMM runs no slice program."""
+    return x if r0 == 0 and r1 == x.shape[0] else x[r0:r1]
 
 
 def __getattr__(name):
@@ -1402,8 +1442,9 @@ class SynergyRuntime:
                     job_class: Optional[str] = None,
                     observe_acts: bool = True,
                     qos: Optional[QosTag] = None) -> RuntimeFuture:
-        """Split one GEMM's tile jobs across the pool as row panels; the
-        future's result is the merged ``act(A @ B + bias)``.
+        """Split one GEMM's tile jobs across the pool as the row panels of
+        :func:`row_panels`; the future's result is the merged
+        ``act(A @ B + bias)``.
 
         Dequant-aware accumulation: every panel executes at fp32 output
         precision (a quantized engine's dequant epilogue lands in fp32)
@@ -1441,11 +1482,12 @@ class SynergyRuntime:
         must not have every sub-submission fold an extra EMA update, or
         batched and per-slot decode would calibrate — and therefore
         quantize — differently."""
-        with annotate("repro/runtime/submit"):
+        ts_m = jobset.ts_m
+        panels = row_panels(a.shape[0], *b.shape, ts_m)
+        with annotate("repro/runtime/submit", panels=len(panels),
+                      rows_per_panel=panels[0][1]):
             import jax.numpy as jnp
-            ts_m = jobset.ts_m
-            m = a.shape[0]
-            gm, gn = jobset.grid
+            gn = jobset.grid[1]
             j = next(jobset.jobs())
             final_dtype = out_dtype or a.dtype
             int8_ok = _admits_int8(job_class)
@@ -1460,16 +1502,16 @@ class SynergyRuntime:
                     def fn(eng: Engine):
                         fn8 = getattr(eng, "execute_int8", None)
                         if fn8 is not None:
-                            return fn8(a_q[r0:r1], qw, tile=tile_t)
+                            return fn8(_rows(a_q, r0, r1), qw, tile=tile_t)
                         # any engine can compute the exact integer partial
                         # through the shared kernel (steals/hotplug-safe)
                         from repro.kernels.qmm import qmm_matmul
-                        return qmm_matmul(a_q[r0:r1], qw.q, qw.scale,
+                        return qmm_matmul(_rows(a_q, r0, r1), qw.q, qw.scale,
                                           fuse_dequant=False, tile=tile_t)
                     return fn
 
-                units = [(make_qfn(t1 * ts_m, min((t1 + 1) * ts_m, m)),
-                          gn, j.macs, j.bytes_moved) for t1 in range(gm)]
+                units = [(make_qfn(r0, r1), -(-(r1 - r0) // ts_m) * gn,
+                          j.macs, j.bytes_moved) for r0, r1 in panels]
 
                 def merge_q(parts: list):
                     from repro.quant.quantize import dequant_finish
@@ -1486,16 +1528,14 @@ class SynergyRuntime:
             def make_fn(r0: int, r1: int):
                 def fn(eng: Engine):
                     ex = getattr(eng, "execute_weight_only", eng.execute)
-                    return ex(a[r0:r1], b, bias=bias,
+                    return ex(_rows(a, r0, r1), b, bias=bias,
                               activation=activation, tile=tile,
                               out_dtype=jnp.float32,
                               precision=precision)
                 return fn
 
-            units = []
-            for t1 in range(gm):
-                r0, r1 = t1 * ts_m, min((t1 + 1) * ts_m, m)
-                units.append((make_fn(r0, r1), gn, j.macs, j.bytes_moved))
+            units = [(make_fn(r0, r1), -(-(r1 - r0) // ts_m) * gn,
+                      j.macs, j.bytes_moved) for r0, r1 in panels]
 
             def merge(parts: list):
                 y = (parts[0] if len(parts) == 1

@@ -528,3 +528,209 @@ def test_cnn_forward_bitwise_equal_with_profiler_on_and_off(tmp_path):
         finally:
             jax.profiler.stop_trace()
     assert np.array_equal(on, off)
+
+
+# ----------------------------------------------- panel plan (row_panels)
+
+def _cifar_full_gemms(frames):
+    """The (m, k, n, JobSet) of every CONV (as im2col) and FC GEMM of one
+    CIFAR_full call of ``frames`` frames, read off a shape-only trace."""
+    from repro.configs.paper_cnns import CIFAR_FULL
+    from repro.models.cnn import cnn_forward, init_cnn
+    params = init_cnn(CIFAR_FULL, jax.random.key(0))
+    x = jax.ShapeDtypeStruct((frames, CIFAR_FULL.input_hw,
+                              CIFAR_FULL.input_hw, CIFAR_FULL.cin),
+                             jnp.float32)
+    tr = SynergyTrace()
+    with tr.activate():
+        jax.eval_shape(lambda x: cnn_forward(CIFAR_FULL, params, x), x)
+    return [(js.m, js.k, js.n, js) for js in tr.jobsets]
+
+
+def _check_plan(panels, m, ts_m, js):
+    """The panels cover [0, m) once, in order; every panel but the last
+    is a whole number of tile rows; their tile jobs are the JobSet's."""
+    assert panels[0][0] == 0 and panels[-1][1] == m
+    assert all(p[1] == q[0] for p, q in zip(panels, panels[1:]))
+    assert all((r1 - r0) % ts_m == 0 for r0, r1 in panels[:-1])
+    assert all(r1 > r0 for r0, r1 in panels)
+    gn = js.grid[1]
+    assert sum(-(-(r1 - r0) // ts_m) * gn for r0, r1 in panels) \
+        == js.num_jobs
+
+
+@pytest.mark.parametrize("layer", range(4))
+@pytest.mark.parametrize("frames", [1, 32, 64])
+def test_row_panels_one_panel_per_cifar_full_gemm_on_the_tpu(frames, layer):
+    from repro.soc.runtime import row_panels
+    gemms = _cifar_full_gemms(frames)
+    assert len(gemms) == 4          # conv0, conv1, conv2, fc
+    m, k, n, js = gemms[layer]
+    panels = row_panels(m, k, n, js.ts_m, backend="tpu")
+    assert panels == [(0, m)]
+    _check_plan(panels, m, js.ts_m, js)
+
+
+@pytest.mark.parametrize("backend", [None, "cpu", "gpu"])
+@pytest.mark.parametrize("m,k,n,ts_m", [(300, 64, 48, 32),
+                                         (65536, 75, 32, 32),
+                                         (64, 1024, 10, 32),
+                                         (1, 1024, 10, 32),
+                                         (4096, 2048, 8192, 32)])
+def test_row_panels_at_a_zero_floor_are_the_grid_rows(m, k, n, ts_m,
+                                                      backend):
+    from repro.soc.runtime import row_panels
+    panels = row_panels(m, k, n, ts_m, backend=backend)
+    assert panels == [(t * ts_m, min((t + 1) * ts_m, m))
+                      for t in range(-(-m // ts_m))]
+    _check_plan(panels, m, ts_m, JobSet.for_gemm(0, m, n, k, ts_m))
+
+
+@pytest.mark.parametrize("m,rows", [(8192, 4096), (16384, 4096),
+                                    (10000, 4096)])
+def test_row_panels_split_a_gemm_above_the_floor(m, rows):
+    # a granite prefill FFN GEMM (k 2048, n 8192): 32 x 2048 x 8192 MACs
+    # per tile row, so 128 tile rows (4096 rows) reach the 6e10 floor
+    from repro.soc.runtime import PANEL_FLOOR_MACS, row_panels
+    k, n, ts_m = 2048, 8192, 32
+    panels = row_panels(m, k, n, ts_m, backend="tpu")
+    t = rows // ts_m
+    assert t & (t - 1) == 0                              # a power of two
+    assert t * ts_m * k * n >= PANEL_FLOOR_MACS["tpu"]
+    assert t // 2 * ts_m * k * n < PANEL_FLOOR_MACS["tpu"]
+    assert [r1 - r0 for r0, r1 in panels[:-1]] == [rows] * (len(panels) - 1)
+    assert len(panels) == -(-m // rows) >= 2
+    _check_plan(panels, m, ts_m, JobSet.for_gemm(0, m, n, k, ts_m))
+
+
+@pytest.mark.parametrize("n_engines", [1, 3])
+def test_panels_do_not_depend_on_the_pool_or_its_rates(monkeypatch,
+                                                       n_engines):
+    # a floor of 4 tile rows' MACs: a 32-row-tile GEMM of 512 rows
+    # dispatches 4 panels of 128 rows, whatever engines run them and
+    # however often their rates are recalibrated
+    from repro.soc import runtime as rt_mod
+    m, k, n, ts = 512, 32, 16, 32
+    monkeypatch.setitem(rt_mod.PANEL_FLOOR_MACS, jax.default_backend(),
+                        4 * ts * k * n)
+    a, b = _ab(m, k, n, seed=4)
+    js = JobSet.for_gemm(0, m, n, k, ts)
+    engines = [_DelayEngine(f"r{i}", macs_per_s=(i + 1) * 1e9, seed=i,
+                            max_delay_s=0.002) for i in range(n_engines)]
+    with SynergyRuntime(engines, recalibrate_every=1) as rt:
+        for _ in range(3):
+            y = rt.submit_gemm(a, b, jobset=js,
+                               tile=(ts, ts, ts)).result(60)
+        st = rt.stats()
+    assert st["total_panels"] == 3 * 4 == sum(e.executed for e in engines)
+    assert st["total_jobs"] == 3 * js.num_jobs
+    np.testing.assert_allclose(np.asarray(y), np.asarray(jnp.dot(a, b)),
+                               rtol=1e-4, atol=1e-4)
+
+
+def _cifar_calls(frames, n_calls=2):
+    from repro.configs.paper_cnns import CIFAR_FULL
+    from repro.models.cnn import init_cnn
+    params = init_cnn(CIFAR_FULL, jax.random.key(3))
+    xs = [jax.random.normal(jax.random.key(10 + i),
+                            (frames, 32, 32, 3)) for i in range(n_calls)]
+    return CIFAR_FULL, params, xs
+
+
+def _run_cifar(pool, net, params, xs, job_class=None):
+    from repro.models.cnn import cnn_forward
+    with SynergyRuntime(pool()) as rt:
+        ys = [np.asarray(cnn_forward(net, params, x, runtime=rt,
+                                     job_class=job_class)) for x in xs]
+        return ys, rt.stats()
+
+
+class _RowExactEngine(Engine):
+    """A GEMM whose every output row is rounded the same whatever rows
+    come with it (a product and a sum over k, no blocked dot), so a
+    split and the whole GEMM must agree bit for bit."""
+
+    def __init__(self):
+        super().__init__("row-exact", {CAP_GEMM, "epilogue"},
+                         cost=CostModel(macs_per_s=1e9))
+
+    def execute(self, a, b, *, bias=None, activation=None, tile=None,
+                out_dtype=None, precision=None):
+        y = (a[:, :, None] * b[None, :, :]).sum(1)
+        if bias is not None:
+            y = y + bias
+        if activation is not None:
+            y = activation(y)
+        return y.astype(out_dtype or a.dtype)
+
+
+def _int8_pool():
+    from repro.quant.engine import QuantizedEngine
+    return [QuantizedEngine(get_engine("xla"))]
+
+
+def _forced_tpu_floor(monkeypatch):
+    from repro.soc import runtime as rt_mod
+    monkeypatch.setitem(rt_mod.PANEL_FLOOR_MACS, jax.default_backend(),
+                        rt_mod.PANEL_FLOOR_MACS["tpu"])
+
+
+@pytest.mark.parametrize("pool,job_class",
+                         [(lambda: [_RowExactEngine()], None),
+                          (_int8_pool, "decode")],
+                         ids=["row-exact", "int8-decode"])
+@pytest.mark.parametrize("frames", [1, 4])
+def test_cnn_forward_under_the_tpu_floor_matches_the_tile_row_split(
+        monkeypatch, frames, pool, job_class):
+    net, params, xs = _cifar_calls(frames)
+    split, st_split = _run_cifar(pool, net, params, xs, job_class)
+    _forced_tpu_floor(monkeypatch)
+    whole, st_whole = _run_cifar(pool, net, params, xs, job_class)
+    for y0, y1 in zip(split, whole):
+        assert np.array_equal(y0, y1)
+    assert st_whole["total_jobs"] == st_split["total_jobs"]
+    assert st_whole["total_panels"] == 4 * len(xs)
+    gms = [-(-m // 32) for m, *_ in _cifar_full_gemms(frames)]
+    assert st_split["total_panels"] == sum(gms) * len(xs)
+
+
+@pytest.mark.parametrize("frames", [1, 4])
+def test_cnn_forward_under_the_tpu_floor_is_the_engines_own_call(
+        monkeypatch, frames):
+    # On an xla pool a one-panel GEMM is the engine's call on the whole
+    # GEMM.  Against the tile-row split it agrees to rounding only: XLA's
+    # CPU dot blocks its contraction by the row count.
+    from repro.models.cnn import cnn_forward
+    net, params, xs = _cifar_calls(frames)
+    split, st_split = _run_cifar(lambda: [get_engine("xla")], net, params,
+                                 xs)
+    _forced_tpu_floor(monkeypatch)
+    whole, st_whole = _run_cifar(lambda: [get_engine("xla")], net, params,
+                                 xs)
+    for x, y0, y1 in zip(xs, split, whole):
+        own = np.asarray(cnn_forward(net, params, x, engine="xla"))
+        assert np.array_equal(y1, own)
+        np.testing.assert_allclose(y1, y0, rtol=1e-5, atol=1e-5)
+    assert st_whole["total_jobs"] == st_split["total_jobs"]
+    assert st_whole["total_panels"] == 4 * len(xs)
+
+
+def test_submit_span_is_tagged_with_the_panel_plan(monkeypatch, tmp_path):
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    _forced_tpu_floor(monkeypatch)
+    net, params, xs = _cifar_calls(2, n_calls=1)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _run_cifar(lambda: [get_engine("xla")], net, params, xs)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                    "*", "*.xplane.pb"))
+    tags = [dict(e.stats) for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events
+            if e.name == "repro/runtime/submit"]
+    ms = [m for m, *_ in _cifar_full_gemms(2)]
+    assert [t["panels"] for t in tags] == [1] * 4
+    assert [t["rows_per_panel"] for t in tags] == ms
