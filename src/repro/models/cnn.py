@@ -1,10 +1,12 @@
-"""CNNs via conv-as-tiled-GEMM — the paper's own benchmark networks.
+"""CNNs via conv-as-tiled-GEMM — the paper's own benchmark networks, and
+ResNet-50 v1.5 (He et al., arXiv:1512.03385) from the same layer kinds
+plus bottleneck blocks.
 
 Every CONV layer lowers to im2col + :func:`synergy_matmul` (so its tile-job
 decomposition is visible to the schedulers), pooling/activation/FC stay on
 the "CPU side" exactly as in the paper (§3.1.4).  ``build_simnet`` exports
-the same network as a :class:`repro.core.scheduler.SimNet` for the
-discrete-event runtime reproduction.
+a linear conv/pool/fc network as a :class:`repro.core.scheduler.SimNet`
+for the discrete-event runtime reproduction.
 
 Layer dims are modeled from the Darknet/Caffe configs the paper trained
 (Table 2); per-frame op counts land within ~10-20% of the paper's reported
@@ -28,7 +30,8 @@ from repro.obs.trace import annotate
 
 __all__ = ["CNNConfig", "init_cnn", "cnn_forward", "build_simnet",
            "conv_jobsets", "conv_graph_steps", "conv_wave_graph",
-           "maxpool2d", "cnn_flops_per_frame"]
+           "maxpool2d", "maxpool_window", "fold_batchnorm",
+           "cnn_flops_per_frame"]
 
 
 def maxpool2d(x: jax.Array, size: int) -> jax.Array:
@@ -40,11 +43,58 @@ def maxpool2d(x: jax.Array, size: int) -> jax.Array:
     x = x[:, : h - h % size, : w - w % size, :]
     return x.reshape(n, h // size, size, w // size, size, c).max(axis=(2, 4))
 
+
+def maxpool_window(x: jax.Array, k: int, stride: int, pad: int) -> jax.Array:
+    """Max pool over k x k windows at ``stride``, the border padded with
+    -inf by ``pad`` (windows may overlap): ResNet's 3x3/2 pad-1 pool."""
+    return jax.lax.reduce_window(
+        x, jnp.array(-jnp.inf, x.dtype), jax.lax.max, (1, k, k, 1),
+        (1, stride, stride, 1), ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+
+
+def fold_batchnorm(w: jax.Array, b: jax.Array, gamma: jax.Array,
+                   beta: jax.Array, mean: jax.Array, var: jax.Array,
+                   eps: float = 1e-5) -> tuple[jax.Array, jax.Array]:
+    """A convolution (HWIO ``w``, bias ``b``) followed by inference batch
+    norm, as one convolution: ``(w', b')`` with
+    ``conv(x, w') + b' == gamma * (conv(x, w) + b - mean) /
+    sqrt(var + eps) + beta``.  How a trained checkpoint's BN layers are
+    loaded into a network's ``conv``/``bottleneck`` parameters."""
+    scale = gamma / jnp.sqrt(var + eps)
+    return w * scale, (b - mean) * scale + beta
+
+
 # layer spec forms:
-#   ("conv", cout, k, stride, pad)
-#   ("pool", size)           max pool, stride == size
-#   ("fc", n_out)
+#   ("conv", cout, k, stride, pad)   conv + ReLU
+#   ("pool", size)                   max pool, stride == size, odd edges cut
+#   ("fc", n_out)                    ReLU except on the last fc
+#   ("maxpool", k, stride, pad)      max pool, -inf padding, may overlap
+#   ("bottleneck", width, cout, stride)
+#       relu(c(b(a(x))) + shortcut(x)): a 1x1 to width + ReLU, b 3x3/stride
+#       pad 1 + ReLU, c 1x1 to cout; the shortcut a 1x1/stride projection
+#       where stride != 1 or cin != cout, else the identity (ResNet v1.5)
+#   ("gap",)                         mean over H and W -> (N, C)
 Layer = tuple
+KINDS = ("conv", "pool", "fc", "maxpool", "bottleneck", "gap")
+
+
+def block_convs(spec: Layer, cin: int) -> list[tuple]:
+    """``(part, k, stride, pad, cin, cout, relu)`` of each convolution of a
+    bottleneck ``spec`` whose input has ``cin`` channels, in the order
+    they run; the ``proj`` shortcut only where the block needs one."""
+    _, width, cout, s = spec
+    convs = [("a", 1, 1, 0, cin, width, True),
+             ("b", 3, s, 1, width, width, True),
+             ("c", 1, 1, 0, width, cout, False)]
+    if projects(spec, cin):
+        convs.append(("proj", 1, s, 0, cin, cout, False))
+    return convs
+
+
+def projects(spec: Layer, cin: int) -> bool:
+    """Whether a bottleneck ``spec`` on ``cin`` channels has a projection
+    shortcut (ResNet's option B): where it strides or widens."""
+    return spec[3] != 1 or cin != spec[2]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,7 +123,27 @@ class CNNConfig:
             elif spec[0] == "fc":
                 h = w = 1
                 c = spec[1]
+            elif spec[0] == "maxpool":
+                _, k, s, p = spec
+                h, w = conv_out_shape(h, w, k, k, s, p)
+            elif spec[0] == "bottleneck":
+                h, w = conv_out_shape(h, w, 3, 3, spec[3], 1)
+                c = spec[2]
+            elif spec[0] == "gap":
+                h = w = 1
+            else:
+                raise ValueError(f"{self.name}: unknown layer kind "
+                                 f"{spec[0]!r} (known: {KINDS})")
         return out, (h, w, c)
+
+    def check_linear_chain(self, what: str) -> None:
+        """Raise unless the net is a linear conv/pool/fc chain, the only
+        form ``what`` (the DES export, the serving prefill graph) takes."""
+        for spec in self.layers:
+            if spec[0] not in ("conv", "pool", "fc"):
+                raise NotImplementedError(
+                    f"{what} takes a linear conv/pool/fc chain; "
+                    f"{self.name} has a {spec[0]!r} layer")
 
 
 def init_cnn(cfg: CNNConfig, key: jax.Array, dtype=jnp.float32) -> dict:
@@ -93,22 +163,59 @@ def init_cnn(cfg: CNNConfig, key: jax.Array, dtype=jnp.float32) -> dict:
             scale = (2.0 / n_in) ** 0.5
             params[f"fc{i}_w"] = (jax.random.normal(sub, (n_in, n_out)) * scale).astype(dtype)
             params[f"fc{i}_b"] = jnp.zeros((n_out,), dtype)
+        elif spec[0] == "bottleneck":
+            for part, k, _, _, ci, co, _ in block_convs(spec, c):
+                key, sub = jax.random.split(key)
+                # He scale; the branch's last conv starts small, as a
+                # trained block's last BN scale is, so the residual sum
+                # stays of unit order through the stack
+                scale = ((2.0 / (k * k * ci)) ** 0.5
+                         * (0.2 if part == "c" else 1.0))
+                params[f"block{i}_{part}_w"] = (
+                    jax.random.normal(sub, (k, k, ci, co)) * scale
+                ).astype(dtype)
+                params[f"block{i}_{part}_b"] = jnp.zeros((co,), dtype)
     return params
 
 
 def _conv_via_jobs(x, w, b, stride, pad, tile, name, engine=None,
-                   job_class=None):
-    """CONV -> im2col -> synergy_matmul (tile jobs) -> bias+relu epilogue."""
+                   job_class=None, activation=jax.nn.relu):
+    """CONV -> im2col -> synergy_matmul (tile jobs) -> bias + activation
+    epilogue.  A 1x1 convolution without padding reads its A operand
+    straight from the (strided) input: no gather."""
     kh, kw, cin, cout = w.shape
     n, h, wd, _ = x.shape
     oh, ow = conv_out_shape(h, wd, kh, kw, stride, pad)
     with annotate("repro/cnn/im2col"):
-        a = im2col(x, kh, kw, stride, pad).reshape(n * oh * ow,
-                                                   kh * kw * cin)
+        if kh == kw == 1 and pad == 0:
+            xs = x if stride == 1 else x[:, ::stride, ::stride, :]
+            a = xs.reshape(n * oh * ow, cin)
+        else:
+            a = im2col(x, kh, kw, stride, pad).reshape(n * oh * ow,
+                                                       kh * kw * cin)
     y = synergy_matmul(a, w.reshape(-1, cout), bias=b,
-                       activation=jax.nn.relu, tile=tile, name=name,
+                       activation=activation, tile=tile, name=name,
                        engine=engine, job_class=job_class)
     return y.reshape(n, oh, ow, cout)
+
+
+def _bottleneck(x, params, i, spec, tile, name, engine, job_class):
+    """One ResNet v1.5 bottleneck block (layer ``i``): its convolutions
+    through :func:`_conv_via_jobs`, then ``relu(branch + shortcut)``."""
+    def conv(src, part, s, p, relu):
+        return _conv_via_jobs(
+            src, params[f"block{i}_{part}_w"], params[f"block{i}_{part}_b"],
+            s, p, tile, f"{name}/block{i}_{part}", engine=engine,
+            job_class=job_class, activation=jax.nn.relu if relu else None)
+
+    y = shortcut = x
+    for part, _, s, p, _, _, relu in block_convs(spec, x.shape[-1]):
+        if part == "proj":
+            shortcut = conv(x, part, s, p, relu)
+        else:
+            y = conv(y, part, s, p, relu)
+    with annotate("repro/cnn/residual"):
+        return jax.nn.relu(y + shortcut)
 
 
 def cnn_forward(cfg: CNNConfig, params: dict, x: jax.Array, *,
@@ -142,7 +249,8 @@ def _cnn_forward(cfg: CNNConfig, params: dict, x: jax.Array, *,
                  engine: str | None = None,
                  job_class: str | None = None) -> jax.Array:
     shapes, _ = cfg.trace_shapes()
-    for i, (spec, *_rest) in enumerate(shapes):
+    stage = index = 0
+    for i, (spec, _, _, c) in enumerate(shapes):
         if spec[0] == "conv":
             _, cout, k, s, p = spec
             x = _conv_via_jobs(x, params[f"conv{i}_w"], params[f"conv{i}_b"],
@@ -151,6 +259,20 @@ def _cnn_forward(cfg: CNNConfig, params: dict, x: jax.Array, *,
         elif spec[0] == "pool":
             with annotate("repro/cnn/pool"):
                 x = maxpool2d(x, spec[1])
+        elif spec[0] == "maxpool":
+            with annotate("repro/cnn/pool"):
+                x = maxpool_window(x, *spec[1:])
+        elif spec[0] == "bottleneck":
+            # a stage starts at each block with a projection shortcut
+            if projects(spec, c):
+                stage, index = stage + 1, 0
+            with annotate("repro/cnn/block", stage=stage, index=index):
+                x = _bottleneck(x, params, i, spec, cfg.tile, cfg.name,
+                                engine, job_class)
+            index += 1
+        elif spec[0] == "gap":
+            with annotate("repro/cnn/gap"):
+                x = x.mean(axis=(1, 2))
         elif spec[0] == "fc":
             n = x.shape[0]
             x = x.reshape(n, -1)
@@ -173,6 +295,11 @@ def cnn_flops_per_frame(cfg: CNNConfig) -> int:
             total += 2 * oh * ow * cout * k * k * c
         elif spec[0] == "fc":
             total += 2 * h * w * c * spec[1]
+        elif spec[0] == "bottleneck":
+            oh, ow = conv_out_shape(h, w, 3, 3, spec[3], 1)
+            for part, k, _, _, ci, co, _ in block_convs(spec, c):
+                pixels = h * w if part == "a" else oh * ow
+                total += 2 * pixels * co * k * k * ci
     return total
 
 
@@ -187,6 +314,7 @@ def conv_jobsets(cfg: CNNConfig, n_frames: int = 1, *,
     (``n_frames`` = all frames of an admission wave), so server prefill
     busy-seconds and simulator busy-seconds read the same cost model over
     the same jobs by construction."""
+    cfg.check_linear_chain("conv_jobsets")
     out: list[tuple[int, JobSet]] = []
     shapes, _ = cfg.trace_shapes()
     conv_id = 0
@@ -209,6 +337,7 @@ def conv_graph_steps(cfg: CNNConfig) -> list[tuple]:
     pool sizes between the previous conv and this one.  The conv
     front-end ends at the first FC layer (matching the serving prefill
     chain)."""
+    cfg.check_linear_chain("conv_graph_steps")
     out: list[tuple] = []
     shapes, _ = cfg.trace_shapes()
     pools: list[int] = []
@@ -292,6 +421,7 @@ def build_simnet(cfg: CNNConfig) -> SimNet:
 
     CONV layers -> accelerated tile-job stages (+ im2col CPU cost);
     pool/fc -> CPU stages; plus the paper's normalization preprocessing."""
+    cfg.check_linear_chain("build_simnet")
     layers: list[SimLayer] = []
     shapes, _ = cfg.trace_shapes()
     # normalization / scaling preprocessing (§3.1.4)
