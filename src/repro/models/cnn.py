@@ -22,7 +22,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core.im2col import conv2d_gemm, conv_out_shape, im2col
+from repro.core.im2col import conv_out_shape, im2col_operand, view_operand
 from repro.core.job import JobSet
 from repro.core.scheduler import SimLayer, SimNet
 from repro.core.synergy_mm import synergy_matmul
@@ -181,18 +181,18 @@ def init_cnn(cfg: CNNConfig, key: jax.Array, dtype=jnp.float32) -> dict:
 def _conv_via_jobs(x, w, b, stride, pad, tile, name, engine=None,
                    job_class=None, activation=jax.nn.relu):
     """CONV -> im2col -> synergy_matmul (tile jobs) -> bias + activation
-    epilogue.  A 1x1 convolution without padding reads its A operand
-    straight from the (strided) input: no gather."""
+    epilogue.  The A operand comes from the jitted patch program, except
+    that a 1x1 convolution without padding reads it straight from the
+    (strided) input: no gather.  The span's ``path`` tag says which."""
     kh, kw, cin, cout = w.shape
     n, h, wd, _ = x.shape
     oh, ow = conv_out_shape(h, wd, kh, kw, stride, pad)
-    with annotate("repro/cnn/im2col"):
-        if kh == kw == 1 and pad == 0:
-            xs = x if stride == 1 else x[:, ::stride, ::stride, :]
-            a = xs.reshape(n * oh * ow, cin)
+    view = kh == kw == 1 and pad == 0
+    with annotate("repro/cnn/im2col", path="view" if view else "patches"):
+        if view:
+            a = view_operand(x, stride)
         else:
-            a = im2col(x, kh, kw, stride, pad).reshape(n * oh * ow,
-                                                       kh * kw * cin)
+            a = im2col_operand(x, kh, kw, stride, pad)
     y = synergy_matmul(a, w.reshape(-1, cout), bias=b,
                        activation=activation, tile=tile, name=name,
                        engine=engine, job_class=job_class)

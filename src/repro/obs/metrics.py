@@ -4,8 +4,9 @@ Design rule (ISSUE 8): the runtime and server do NOT maintain parallel
 counters for the registry.  ``Telemetry`` / ``ServeStats`` /
 ``TenantStats`` / ``rt.stats()`` stay the single source of truth and the
 registry is populated from those *views* at collect time
-(:func:`collect_runtime` / :func:`collect_server` /
-:func:`collect_calibrator`, all invoked by :func:`render_prometheus`).
+(:func:`collect_runtime` / :func:`collect_im2col` /
+:func:`collect_server` / :func:`collect_calibrator`, all invoked by
+:func:`render_prometheus`).
 The only per-observation instrument is the per-tenant queue-wait
 histogram, which the server feeds behind a single attribute check — its
 ``observe()`` is allocation-free (fixed bucket list, bisect index).
@@ -319,6 +320,22 @@ def collect_runtime(rt, registry: MetricsRegistry = REGISTRY) -> None:
                      "death").set_total(st.get("orphan_reseeds", 0))
 
 
+def collect_im2col(registry: MetricsRegistry = REGISTRY) -> None:
+    """Feed the registry from the process-wide im2col counts
+    (:func:`repro.core.im2col.im2col_counts`)."""
+    from repro.core.im2col import im2col_counts
+    st = im2col_counts()
+    calls = registry.counter("repro_cnn_im2col_calls_total",
+                             "conv A operands built: patches (the jitted "
+                             "patch program) or view (a 1x1 read without "
+                             "a gather)", ("path",))
+    for path in ("patches", "view"):
+        calls.labels(path).set_total(st[path])
+    registry.counter("repro_cnn_im2col_programs_total",
+                     "builds of the jitted patch program, one per input "
+                     "shape and conv geometry").set_total(st["programs"])
+
+
 def collect_server(srv, registry: MetricsRegistry = REGISTRY) -> None:
     """Feed the registry from ``ServeStats`` / ``TenantStats`` views and
     live queue/in-flight occupancy."""
@@ -401,6 +418,7 @@ def render_prometheus(*, runtime=None, server=None, engines=(),
     Prometheus text exposition format."""
     if runtime is not None:
         collect_runtime(runtime, registry)
+        collect_im2col(registry)
         for eng in getattr(runtime, "engines", ()):
             collect_calibrator(eng, registry)
     if server is not None:

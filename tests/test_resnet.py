@@ -3,8 +3,8 @@ the CPU: the whole network against the benchmark's plain reference
 (``bench/reference/resnet50.py``) on seeded random weights, with and
 without the runtime, and each new piece alone: the 1x1 convolution that
 reads its input without a gather, the overlapping max pool, batch-norm
-folding, the block spans, and the errors for layer kinds a function does
-not take."""
+folding, the block spans, the im2col path tags and counters, and the
+errors for layer kinds a function does not take."""
 
 from __future__ import annotations
 
@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from bench import core
-from repro.core.im2col import im2col
+from repro.core.im2col import im2col, im2col_counts, im2col_operand
 from repro.models import cnn
 from repro.models.cnn import CNNConfig, cnn_forward, init_cnn
 
@@ -79,8 +79,22 @@ def test_one_by_one_conv_reads_without_a_gather(monkeypatch, stride):
     def no_gather(*a, **kw):
         raise AssertionError("a 1x1 convolution ran the im2col gather")
 
-    monkeypatch.setattr(cnn, "im2col", no_gather)
+    tags = []
+    real = cnn.annotate
+
+    def record(name, **kw):
+        if name == "repro/cnn/im2col":
+            tags.append(kw)
+        return real(name, **kw)
+
+    monkeypatch.setattr(cnn, "im2col_operand", no_gather)
+    monkeypatch.setattr(cnn, "annotate", record)
+    before = im2col_counts()
     got = cnn._conv_via_jobs(x, w, b, stride, 0, 32, "one", activation=None)
+    after = im2col_counts()
+    assert tags == [{"path": "view"}]
+    assert after["view"] - before["view"] == 1
+    assert after["patches"] == before["patches"]
     oh = (9 - 1) // stride + 1
     assert got.shape == (2, oh, oh, 7)
     np.testing.assert_allclose(np.asarray(got).reshape(-1, 7),
@@ -144,6 +158,69 @@ def test_block_residual_and_gap_spans(case, monkeypatch):
     # 1 stem gather and one per block's 3x3; the 1x1s open the span but
     # gather nothing
     assert names.count("repro/cnn/im2col") == 1 + 5 * 3 + 4
+
+
+def test_im2col_span_tag_names_the_path(case, monkeypatch):
+    """One ``repro/cnn/im2col`` span per convolution, tagged ``patches``
+    for the stem and each 3x3 and ``view`` for each 1x1, in the order
+    they run: a, b, c, then the projection."""
+    params, x, _ = case
+    tags = []
+    real = cnn.annotate
+
+    def record(name, **kw):
+        if name == "repro/cnn/im2col":
+            tags.append(kw["path"])
+        return real(name, **kw)
+
+    monkeypatch.setattr(cnn, "annotate", record)
+    cnn_forward(NET, params, x)
+    want = ["patches"]
+    for spec, _, _, cin in NET.trace_shapes()[0]:
+        if spec[0] == "bottleneck":
+            want += ["view", "patches", "view"]
+            want += ["view"] if cnn.projects(spec, cin) else []
+    assert tags == want
+    assert want.count("view") == 5 * 2 + 4
+
+
+def test_second_forward_builds_no_im2col_program(case):
+    """Once a shape has run, a forward at the same shapes dispatches the
+    patch program again and builds none: one call per patch-gathered
+    conv (the stem and five 3x3s), one view per 1x1."""
+    params, x, _ = case
+    cnn_forward(NET, params, x)
+    first = im2col_counts()
+    cnn_forward(NET, params, x)
+    second = im2col_counts()
+    assert second["programs"] == first["programs"]
+    assert second["patches"] - first["patches"] == 1 + 5
+    assert second["view"] - first["view"] == 5 * 2 + 4
+
+
+def test_a_new_geometry_builds_one_program():
+    x = jnp.ones((1, 17, 19, 2))
+    before = im2col_counts()
+    im2col_operand(x, 3, 3, 2, 1)
+    im2col_operand(x, 3, 3, 2, 1)
+    after = im2col_counts()
+    assert after["programs"] - before["programs"] == 1
+    assert after["patches"] - before["patches"] == 2
+
+
+def test_prometheus_shows_im2col_counters(case):
+    from repro.obs.metrics import (MetricsRegistry, parse_prometheus,
+                                   render_prometheus)
+    from repro.soc import SynergyRuntime
+    params, x, _ = case
+    with SynergyRuntime(["xla"]) as rt:
+        cnn_forward(NET, params, x, runtime=rt)
+        st = im2col_counts()
+        got = parse_prometheus(render_prometheus(
+            runtime=rt, registry=MetricsRegistry()))
+    calls = {lb["path"]: v for lb, v in got["repro_cnn_im2col_calls_total"]}
+    assert calls == {"patches": st["patches"], "view": st["view"]}
+    assert got["repro_cnn_im2col_programs_total"] == [({}, st["programs"])]
 
 
 BAD = CNNConfig(name="bad", input_hw=8, cin=3,
