@@ -612,7 +612,7 @@ def graph_overlap():
                 x = y.reshape(n_frames, oh, ow, cout)
         return waves * n_frames / (time.perf_counter() - t0)
 
-    def run_graph(rt):
+    def run_dag(rt):
         t0 = time.perf_counter()
         futs = []
         for w in range(waves):
@@ -630,11 +630,11 @@ def graph_overlap():
     with SynergyRuntime(paced_pool(), name="ovl-chain") as rt_c, \
             SynergyRuntime(paced_pool(), name="ovl-graph") as rt_g:
         run_chain(rt_c)                     # warmup: jit compiles
-        run_graph(rt_g)
+        run_dag(rt_g)
         chain_fps, graph_fps, ratios = [], [], []
         for _ in range(reps):
             c = run_chain(rt_c)
-            g = run_graph(rt_g)
+            g = run_dag(rt_g)
             chain_fps.append(c)
             graph_fps.append(g)
             ratios.append(g / c)

@@ -4,8 +4,8 @@ Where :mod:`repro.engines` answers "*which* engine should run this JobSet",
 this package answers "*run it*": a live work-stealing runtime
 (:class:`SynergyRuntime`) with one worker per engine and per-engine job
 deques, the shared steal policy (:mod:`repro.soc.policy`) the discrete-event
-simulator applies, and a virtual-time conformance twin (:class:`SimRuntime`)
-so simulated and live steal decisions agree for identical cost models.
+simulator applies, and a virtual-time replay of one JobSet
+(:class:`SimRuntime`) under that policy, checked against the simulator.
 
     from repro.soc import SynergyRuntime, runtime_scope
 
@@ -30,12 +30,11 @@ from .qos_policy import (BEST_EFFORT, BULK, DEFAULT_CLASS, INTERACTIVE,
                          effective_deadline, qos_victim, queue_insert_index)
 from .runtime import (RuntimeFuture, SynergyRuntime, current_runtime,
                       runtime_scope)
-from .simrt import (SimGraphResult, SimQosResult, SimRuntime,
-                    SimRuntimeResult)
+from .simrt import SimRuntime, SimRuntimeResult
 
 __all__ = [
     "SynergyRuntime", "RuntimeFuture", "runtime_scope", "current_runtime",
-    "SimRuntime", "SimRuntimeResult", "SimGraphResult", "SimQosResult",
+    "SimRuntime", "SimRuntimeResult",
     "GraphNode", "GraphFuture", "GraphCancelled",
     "should_steal", "pick_victim", "lpt_pick",
     "STEAL_RATE_FLOOR", "STEAL_QUEUE_DEPTH",

@@ -26,9 +26,7 @@ Scheduling mechanics (the tentpole invariant): per-node remaining-
 dependency counters are decremented at (tail) panel completion **under
 the manager lock**, and newly ready nodes are LPT-seeded into the
 existing per-engine deques — so work stealing, hotplug rebalances and
-``submit_timeout`` all apply to graph work unchanged, and the virtual-
-time :class:`~repro.soc.simrt.SimRuntime` replays the same decisions via
-``run_graph``.
+``submit_timeout`` all apply to graph work unchanged.
 
 Failure / cancellation: a failed node cancels every not-yet-started
 descendant, and :meth:`GraphFuture.cancel` additionally DRAINS the

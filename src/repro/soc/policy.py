@@ -5,7 +5,7 @@ idle cluster (the idle book), the *stealer* picks a victim queue and moves a
 job.  The decision itself is two pure functions, and the discrete-event
 simulator (:func:`repro.core.scheduler.simulate`), the live
 :class:`repro.soc.SynergyRuntime` workers, and the virtual-time
-:class:`repro.soc.SimRuntime` all import THESE so a steal decision made in
+:meth:`repro.soc.SimRuntime.run` all import THESE so a steal decision made in
 simulation is the decision made on live engines for identical cost models.
 
 The QoS layer (:mod:`repro.soc.qos_policy`) composes with — never replaces

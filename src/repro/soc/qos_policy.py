@@ -1,10 +1,9 @@
 """Pure QoS scheduling policy — priorities, deadlines, fair shares.
 
 Like :mod:`repro.soc.policy`, this module is decision functions ONLY: the
-live :class:`~repro.soc.SynergyRuntime`, the virtual-time
-:class:`~repro.soc.SimRuntime` twin, and the serving admission layer all
-import THESE, so a QoS decision made in simulation is the decision made on
-live engines (the conformance tests assert function identity).
+live :class:`~repro.soc.SynergyRuntime` and the serving admission layer
+both import THESE, so each QoS decision is written once (the tests assert
+function identity).
 
 Semantics
 ---------

@@ -33,6 +33,8 @@ from repro.engines import (CAP_GEMM, CAP_INT8, CostModel,  # noqa: E402
 from repro.soc import (FaultPlan, RetryPolicy,            # noqa: E402
                        SynergyRuntime, wrap_pool)
 
+from _panels import panel_reference                       # noqa: E402
+
 
 class _ChaosEngine(Engine):
     """Identical fp32 math on every instance (placement-independent,
@@ -77,6 +79,7 @@ def test_random_fault_plans_exactly_once_bitwise_no_hangs(plan_seed,
                               (32 * rng.randint(1, 4), d))
             for i in range(rng.randint(2, 4))]
 
+    solo = _ChaosEngine("solo", max_delay_s=0.0)
     with SynergyRuntime(wrap_pool(pool, plan), name="fprop",
                         retry=retry) as rt:
         futs = [rt.submit_gemm(
@@ -91,7 +94,7 @@ def test_random_fault_plans_exactly_once_bitwise_no_hangs(plan_seed,
             assert f.execution_counts == [1] * len(f.execution_counts)
             assert sum(x["jobs"] for x in f.accounting.values()) \
                 == f.jobset.num_jobs
-            ref = jnp.dot(a, w, preferred_element_type=jnp.float32)
+            ref = panel_reference(solo, a, w, 32)
             assert np.array_equal(np.asarray(got), np.asarray(ref))
         stats = rt.stats()
     # every injected fault that raised/corrupted was absorbed as a retry

@@ -5,9 +5,8 @@ deques the moment their predecessors' tail panels land (finish_order
 respects every edge), host gather nodes overlap GEMM nodes, adopted
 ``submit_gemm`` futures complete their node bitwise-identically to a
 serial reference, failures cancel descendants, ``GraphFuture.cancel``
-drains queued-but-unstarted panels (satellite 1), and the virtual-time
-``SimRuntime.run_graph`` replays chain graphs unit-for-unit identically
-to back-to-back ``run()`` calls (the DES-conformance bridge).
+drains queued-but-unstarted panels (satellite 1), and a chain graph
+books exactly the jobs of the same JobSets submitted back to back.
 """
 
 import random
@@ -20,8 +19,10 @@ import pytest
 
 from repro.core.job import JobSet
 from repro.engines import CAP_GEMM, CostModel, Engine, get_engine
-from repro.soc import (GraphCancelled, GraphNode, SimRuntime, SynergyRuntime)
+from repro.soc import GraphCancelled, GraphNode, SynergyRuntime
 from repro.soc.graph import validate_dag
+
+from _panels import panel_reference
 
 
 def _ab(m, k, n, seed=0):
@@ -139,7 +140,9 @@ def test_graph_value_flow_adopted_gemm_bitwise():
     with SynergyRuntime(["F-PE", "S-PE"], name="flow") as rt:
         gf = rt.submit_graph(nodes, [(0, 1), (1, 2), (2, 3)], name="flow")
         vals = gf.result(60)
-    ref = jnp.dot(jax.nn.relu(jnp.dot(a * 2.0, w1)), w2)
+    fpe = get_engine("F-PE")
+    ref = panel_reference(
+        fpe, jax.nn.relu(panel_reference(fpe, a * 2.0, w1, 16)), w2, 16)
     assert np.array_equal(np.asarray(vals[3]), np.asarray(ref))
     assert gf.node_future(1) is not None      # adopted submission futures
     assert gf.node_future(0) is None          # pure host node: no future
@@ -161,7 +164,9 @@ def test_graph_parallel_branches_share_the_pool():
     with SynergyRuntime(["F-PE", "S-PE"], name="fan") as rt:
         gf = rt.submit_graph(nodes, [(0, 2), (1, 2)], name="fan")
         vals = gf.result(60)
-    ref = jnp.dot(a, w) + jnp.dot(a * 3.0, w)
+    fpe = get_engine("F-PE")
+    ref = (panel_reference(fpe, a, w, 16)
+           + panel_reference(fpe, a * 3.0, w, 16))
     assert np.array_equal(np.asarray(vals[2]), np.asarray(ref))
 
 
@@ -289,6 +294,7 @@ def test_random_dag_exactly_once_ordered_and_bitwise(seed):
     for u, v in edges:
         assert pos[u] < pos[v]
     # serial reference, same pred-value accumulation order (edge order)
+    solo = _DelayEngine("solo", max_delay_s=0.0)
     ref: list = [None] * n
     for i in range(n):
         if kinds[i] == "acct":
@@ -297,7 +303,7 @@ def test_random_dag_exactly_once_ordered_and_bitwise(seed):
         for p in preds[i]:
             if ref[p] is not None:
                 x = x + ref[p]
-        ref[i] = jnp.dot(x, w)
+        ref[i] = panel_reference(solo, x, w, 16)
     for i in range(n):
         if kinds[i] == "gemm":
             assert np.array_equal(np.asarray(vals[i]), np.asarray(ref[i])), i
@@ -305,47 +311,24 @@ def test_random_dag_exactly_once_ordered_and_bitwise(seed):
             assert vals[i] is None
 
 
-# --------------------------------------------- SimRuntime virtual-time twin
+# ------------------------------------------------ chain vs back to back
 
-def test_sim_run_graph_chain_matches_back_to_back_runs():
-    """DES conformance bridge: a chain graph replays unit-for-unit like
-    back-to-back run() calls (which are themselves DES-conformant) — at a
-    chain boundary every engine is free, so the release+kick reproduces a
-    fresh run's initial state exactly."""
-    sim = SimRuntime(["F-PE", "S-PE", "NEON"])
+def test_graph_chain_matches_back_to_back_runs():
+    """A chain of accounting nodes books exactly the tile jobs of the same
+    JobSets submitted back to back, and reaps them in chain order."""
     jss = [JobSet.for_gemm(i, 512, 256, 128, 32, name=f"l{i}")
            for i in range(3)]
-    g = sim.run_graph(jss, [(0, 1), (1, 2)])
-    t = 0.0
-    busy = {e.name: 0.0 for e in sim.engines}
-    jobs = {e.name: 0 for e in sim.engines}
-    steals = {e.name: 0 for e in sim.engines}
-    for js in jss:
-        r = sim.run(js)
-        t += r.makespan_s
-        for k in busy:
-            busy[k] += r.per_engine_busy[k]
-            jobs[k] += r.per_engine_jobs[k]
-            steals[k] += r.per_engine_steals[k]
-    assert g.makespan_s == pytest.approx(t, rel=1e-12)
-    for k in busy:
-        assert g.per_engine_busy[k] == pytest.approx(busy[k], rel=1e-12)
-        assert g.per_engine_jobs[k] == jobs[k]
-        assert g.per_engine_steals[k] == steals[k]
-    # node stamps are the chain's running makespans
-    assert g.node_finish_s[-1] == pytest.approx(g.makespan_s, rel=1e-12)
-    assert list(g.node_finish_s) == sorted(g.node_finish_s)
-
-
-def test_sim_run_graph_diamond_topo_order_and_conservation():
-    sim = SimRuntime(["F-PE", "S-PE"])
-    jss = [JobSet.for_gemm(i, 256, 128, 64, 32, name=f"n{i}")
-           for i in range(4)]
-    edges = [(0, 1), (0, 2), (1, 3), (2, 3)]
-    g = sim.run_graph(jss, edges)
-    for u, v in edges:
-        assert g.node_finish_s[u] < g.node_finish_s[v]
-    assert sum(g.per_engine_jobs.values()) == sum(js.num_jobs for js in jss)
-    # parallel branches overlap: strictly faster than the serial chain
-    serial = sum(sim.run(js).makespan_s for js in jss)
-    assert g.makespan_s < serial
+    with SynergyRuntime(["F-PE", "S-PE", "NEON"], name="chain") as rt:
+        gf = rt.submit_graph(jss, [(0, 1), (1, 2)], name="chain")
+        gf.result(60)
+        chain_jobs = rt.stats()["total_jobs"]
+    with SynergyRuntime(["F-PE", "S-PE", "NEON"], name="serial") as rt:
+        futs = []
+        for js in jss:
+            futs.append(rt.submit(js))
+            futs[-1].result(60)
+        serial_jobs = rt.stats()["total_jobs"]
+    assert gf.finish_order == [0, 1, 2]
+    assert sum(a["jobs"] for a in gf.accounting.values()) \
+        == sum(sum(a["jobs"] for a in f.accounting.values()) for f in futs)
+    assert chain_jobs == serial_jobs == sum(js.num_jobs for js in jss)

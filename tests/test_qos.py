@@ -10,10 +10,10 @@ Covers the PR's acceptance surface without the hypothesis dev-dependency
   * live runtime placement: priority-sorted deques, deadline-aware seed
     order, QoS victim choice in ``_try_steal_locked``, and end-to-end
     priority completion ordering behind a gated worker;
-  * live quarantine/readmission of a rate-degraded engine;
-  * :meth:`repro.soc.SimRuntime.run_qos` — deadline verdicts, quarantine
-    exclusion, and seed-map conformance against the live
-    ``_seed_locked`` (shared-function identity asserted too);
+  * live quarantine/readmission of a rate-degraded engine, and degraded
+    placement when every engine is quarantined;
+  * the live ``_seed_locked`` seed map of a tagged batch, pinned
+    (shared-function identity asserted too);
   * serving tenancy: bounded queues + ``AdmissionRejected`` retry-after,
     weighted fair admission, the shed ladder's int8 degradation,
     per-tenant stats, and bitwise token parity of a tenanted server
@@ -34,14 +34,16 @@ from repro.configs import ARCHS, reduced
 from repro.core.job import JobSet
 from repro.core.serving import (Request, ServeTimeoutError, SynergyServer,
                                 TenantStats)
-from repro.engines import CAP_GEMM, CostModel, Engine, get_engine
+from repro.engines import CAP_GEMM, CAP_INT8, CostModel, Engine, get_engine
 from repro.models import init_model
 from repro.soc import (AdmissionRejected, EngineHealth, FairShare,
-                       HealthPolicy, QosClass, QosTag, SimRuntime,
-                       SynergyRuntime, Tenant, effective_deadline,
-                       qos_victim, queue_insert_index)
+                       HealthPolicy, QosClass, QosTag, SynergyRuntime,
+                       Tenant, effective_deadline, qos_victim,
+                       queue_insert_index)
 from repro.soc.qos import BULK, DEFAULT_CLASS
 from repro.soc.runtime import _RuntimeJob, _Submission
+
+from _panels import panel_reference
 
 
 def _cfg():
@@ -274,9 +276,11 @@ def test_quarantine_and_readmission_lifecycle():
     sick = _SickEngine("sick", delay_s=0.008)
     buddy = _SickEngine("buddy", delay_s=0.008)
     with SynergyRuntime([sick, buddy], name="heal", health=pol) as rt:
-        # phase 1: establish a healthy baseline on both workers
+        # phase 1: establish a healthy baseline on both workers (four
+        # panels a GEMM: with one, the buddy can win every race for it and
+        # leave "sick" no baseline to fall from)
         for s in range(8):
-            _gemm(rt, s, affinity="sick").result(30)
+            _gemm(rt, s, m=64, affinity="sick").result(30)
         assert not rt.stats()["engines"]["sick"]["quarantined"]
 
         # phase 2: the sick engine degrades 15x -> quarantine
@@ -320,62 +324,62 @@ def test_health_none_keeps_stats_shape():
     assert st["engines"]["nh"]["quarantined"] is False
 
 
-# --------------------------------------------------- SimRuntime.run_qos
+@pytest.mark.parametrize("int8_buddy", [False, True],
+                         ids=["all-fp32", "int8-buddy"])
+def test_submission_completes_with_every_engine_quarantined(int8_buddy):
+    """With every eligible engine quarantined the seed falls back to
+    degraded placement: the panels land on the quarantined eligible
+    engines (never on a healthy int8 engine an fp32 GEMM may not use) and
+    the GEMM completes, bitwise the per-panel reference."""
+    from repro.quant import QuantizedEngine
+    pool = [_Plain("q0"), _Plain("q1")]
+    if int8_buddy:
+        pool[1] = QuantizedEngine(get_engine("xla"), name="q-int8")
+    fp32 = {e.name for e in pool if CAP_INT8 not in e.capabilities}
+    pol = HealthPolicy(min_probe_samples=10**9)      # no readmission
+    a = jax.random.normal(jax.random.key(0), (64, 32))
+    b = jax.random.normal(jax.random.key(1), (32, 16))
+    js = JobSet.for_gemm(0, 64, 16, 32, 16)
+    with SynergyRuntime(pool, name="allq", health=pol) as rt:
+        with rt._cond:
+            for n in fp32:
+                rt._workers[n].health.enter_quarantine(time.monotonic())
+        fut = rt.submit_gemm(a, b, jobset=js, tile=(16, 16, 16))
+        y = fut.result(60)
+        st = rt.stats()
+    assert all(st["engines"][n]["quarantined"] for n in fp32)
+    assert set(fut.accounting) <= fp32
+    assert sum(x["jobs"] for x in fut.accounting.values()) == js.num_jobs
+    assert np.array_equal(np.asarray(y), np.asarray(
+        panel_reference(_Plain("solo"), a, b, 16)))
+
+
+# ----------------------------------------------------- shared QoS policy
 
 def test_qos_functions_are_shared_objects():
+    import repro.soc.policy as policy
     import repro.soc.qos_policy as qp
     import repro.soc.runtime as runtime
-    import repro.soc.simrt as simrt
-    for mod in (runtime, simrt):
-        assert mod.qos_victim is qp.qos_victim
-        assert mod.queue_insert_index is qp.queue_insert_index
-        assert mod.effective_deadline is qp.effective_deadline
-    import repro.soc.policy as policy
-    assert simrt.lpt_pick is policy.lpt_pick
+    assert runtime.qos_victim is qp.qos_victim
+    assert runtime.queue_insert_index is qp.queue_insert_index
+    assert runtime.effective_deadline is qp.effective_deadline
     assert runtime.lpt_pick is policy.lpt_pick
 
 
-def test_run_qos_priority_and_deadlines_single_engine():
-    """On one engine the schedule is strictly priority-ordered, so the
-    interactive submission finishes after exactly its own service time —
-    a deadline with any slack over that is met no matter how much bulk
-    work was admitted alongside."""
-    eng = get_engine("F-PE")
-    bulk = JobSet.for_gemm(0, 320, 128, 96, 32, name="bulk")
-    inter = JobSet.for_gemm(1, 64, 128, 96, 32, name="inter")
-    j = next(inter.jobs())
-    solo_s = inter.num_jobs * eng.cost.job_time(j.macs, j.bytes_moved)
-    res = SimRuntime(["F-PE"]).run_qos(
-        [(bulk, QosTag(-10)), (inter, QosTag(10, solo_s * 1.01))])
-    assert res.deadline_met == (True, True)      # bulk has no deadline
-    assert res.submission_finish_s[1] == pytest.approx(solo_s, rel=1e-9)
-    assert res.submission_finish_s[1] < res.submission_finish_s[0]
-    assert sum(res.per_engine_jobs.values()) == \
-        bulk.num_jobs + inter.num_jobs
-
-
-def test_run_qos_quarantine_exclusion():
-    js = JobSet.for_gemm(0, 320, 128, 96, 32)
-    res = SimRuntime(["F-PE", "S-PE"]).run_qos([(js, None)],
-                                               quarantined=["S-PE"])
-    assert res.per_engine_jobs["S-PE"] == 0
-    assert res.per_engine_jobs["F-PE"] == js.num_jobs
-    assert set(res.seed_map[0]) == {"F-PE"}
-    with pytest.raises(ValueError, match="every engine quarantined"):
-        SimRuntime(["F-PE"]).run_qos([(js, None)], quarantined=["F-PE"])
-
-
-def test_run_qos_seed_map_conforms_to_live_seeding():
-    """The sim's seed map and the live runtime's ``_seed_locked`` make
-    IDENTICAL placement decisions for identical cost models — deadline
-    sort, LPT pick, and priority insertion are the same shared
-    functions, applied in the same order."""
+def test_seed_map_conforms_to_live_seeding():
+    """The live ``_seed_locked`` places a tagged batch by deadline sort,
+    LPT pick and priority insertion.  The expected map is the one the
+    virtual-time replay of the same batch gave for the same cost models,
+    written out here as a literal."""
     subs = [
         (JobSet.for_gemm(0, 128, 64, 32, 32, name="bulk"), QosTag(-10)),
         (JobSet.for_gemm(1, 64, 64, 32, 32, name="hot"), QosTag(10, 0.5)),
         (JobSet.for_gemm(2, 96, 64, 32, 32, name="mid"), None),
     ]
-    sim = SimRuntime(["F-PE", "S-PE"]).run_qos(subs)
+    F, S = "F-PE", "S-PE"
+    expected = ((S, F, F, S, F, F, S, F),
+                (F, S, F, F),
+                (S, F, F, S, F, F))
 
     rt = SynergyRuntime(["F-PE", "S-PE"])      # never started
     jobs, sids = [], []
@@ -391,11 +395,11 @@ def test_run_qos_seed_map_conforms_to_live_seeding():
             sids.append(sid)
         sub._sid = sid
     rt._seed_locked(jobs, affinity=None)
-    live = [[None] * len(sim.seed_map[s]) for s in range(len(subs))]
+    live = [[None] * len(expected[s]) for s in range(len(subs))]
     for name, w in rt._workers.items():
         for job in w.queue:
             live[job.sub._sid][job.index] = name
-    assert tuple(tuple(m) for m in live) == sim.seed_map
+    assert tuple(tuple(m) for m in live) == expected
 
 
 # -------------------------------------------------------- serving tenancy

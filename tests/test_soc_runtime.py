@@ -25,6 +25,8 @@ from repro.engines import (CAP_GEMM, CostModel, Engine, get_engine,
 from repro.soc import (SimRuntime, SynergyRuntime, current_runtime,
                        runtime_scope, should_steal)
 
+from _panels import panel_reference
+
 
 def _ab(m, k, n, seed=0):
     ka, kb = jax.random.split(jax.random.key(seed))
@@ -113,26 +115,35 @@ def test_current_runtime_scope_nesting():
 
 # ------------------------------------------------------- work conservation
 
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_work_conservation_under_randomized_stealing(seed):
+@pytest.mark.parametrize("seed,m,epilogue",
+                         [(0, 17 * 16, False), (1, 17 * 16, False),
+                          (2, 17 * 16, False), (3, 16, False),
+                          (4, 17 * 16 - 5, False), (5, 17 * 16 - 5, True)],
+                         ids=["0", "1", "2", "one-panel", "ragged",
+                              "bias-relu"])
+def test_work_conservation_under_randomized_stealing(seed, m, epilogue):
     """Every tile job executes exactly once no matter how steals interleave,
-    and the merged result is bit-exact vs the same split executed serially
-    on one engine of the same family."""
+    and the merged result is bit-exact vs the same row panels executed
+    serially on one engine of the same family: for one panel, for many,
+    for a ragged last panel, and with a bias + ReLU epilogue."""
     engines = [_DelayEngine(f"d{i}", macs_per_s=(i + 1) * 1e9,
                             seed=seed * 10 + i) for i in range(3)]
-    a, b = _ab(17 * 16, 40, 24, seed=seed)
-    js = JobSet.for_gemm(0, a.shape[0], 24, 40, 16)
+    a, b = _ab(m, 40, 24, seed=seed)
+    kw = ({"bias": jax.random.normal(jax.random.key(seed), (24,)),
+           "activation": jax.nn.relu} if epilogue else {})
+    js = JobSet.for_gemm(0, m, 24, 40, 16)
+    n_panels = -(-m // 16)
     with SynergyRuntime(engines) as rt:
-        fut = rt.submit_gemm(a, b, jobset=js, tile=(16, 16, 16))
+        fut = rt.submit_gemm(a, b, jobset=js, tile=(16, 16, 16), **kw)
         y = fut.result(60)
-    assert fut.execution_counts == [1] * 17          # exactly once, per panel
+    assert fut.execution_counts == [1] * n_panels    # exactly once, per panel
     acct_jobs = sum(x["jobs"] for x in fut.accounting.values())
-    assert acct_jobs == js.num_jobs == 17 * 2
-    assert sum(e.executed for e in engines) == 17
+    assert acct_jobs == js.num_jobs == n_panels * 2
+    assert sum(e.executed for e in engines) == n_panels
     # bit-exact oracle: same row panels on a single same-family engine
     solo = _DelayEngine("solo", seed=99, max_delay_s=0.0)
-    parts = [solo.execute(a[r:r + 16], b) for r in range(0, a.shape[0], 16)]
-    assert np.array_equal(np.asarray(y), np.asarray(jnp.concatenate(parts)))
+    assert np.array_equal(np.asarray(y),
+                          np.asarray(panel_reference(solo, a, b, 16, **kw)))
 
 
 def test_accounting_submission_conserves_jobs():
