@@ -687,7 +687,11 @@ class SynergyServer:
                 self.close()
                 return self.stats
             if not self.step():
-                break
+                if self._progress is None:
+                    break
+                # nothing moves but the admission's conv chain, still on
+                # the runtime: wait for it, then admit what is queued
+                self._force_finish_progress()
             max_steps -= 1
         self.drain()
         return self.stats
@@ -696,18 +700,7 @@ class SynergyServer:
         """Finish any in-flight chunked admission (replay remainder plus
         the conv chunk chain, blocking under ``submit_timeout``), then
         reap every outstanding in-flight submission."""
-        prog = self._progress
-        if prog is not None:
-            if prog.tok_i < prog.span:
-                self._replay_span(prog, prog.tok_i, prog.span)
-                prog.tok_i = prog.span
-                self.stats.prefill_chunks += 1
-            if not prog.finalized:
-                self._finalize_replay(prog)
-            conv = prog.conv
-            while conv is not None and not conv.done:
-                self._harvest_conv_blocking(conv)
-            self._progress = None
+        self._force_finish_progress()
         while self._inflight:
             self._reap_one()
         return self.stats
@@ -939,7 +932,8 @@ class SynergyServer:
     def _force_finish_progress(self) -> None:
         """Complete the in-flight chunked admission NOW (blocking): drain
         the remaining replay quanta and conv chunk chain.  Used by replay
-        alignment and the snapshot-time quiesce path via ``drain()``."""
+        alignment, by ``run()`` when only the conv chain is left to move,
+        and by ``drain()``."""
         prog = self._progress
         if prog is None:
             return

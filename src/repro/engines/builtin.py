@@ -59,8 +59,9 @@ class XlaEngine(Engine):
 
 
 class PallasTiledEngine(Engine):
-    """The Pallas ``tiled_mm`` kernel — the TPU-native Synergy PE (grid ==
-    job space, VMEM double buffering, fused epilogue).  Interpret-mode
+    """The Pallas ``tiled_mm`` kernel — the TPU-native Synergy PE (a grid
+    of blocks sized by the GEMM's shape, each holding many job tiles, VMEM
+    double buffering, fused epilogue).  Interpret-mode
     capable: explicitly requesting it off-TPU runs the kernel through the
     Pallas interpreter (validation path)."""
 

@@ -1,4 +1,5 @@
-"""Jit'd wrapper for the tiled_mm Pallas kernel: border zero-padding
+"""Jit'd wrapper for the tiled_mm Pallas kernel: picks the GEMM's block
+from its shape and zero-pads a border only where no block fits without
 (paper §3.2.1 "Zero Padding in mm_tile").  This is the execution backend
 of :class:`repro.engines.PallasTiledEngine`; call sites dispatch through
 ``synergy_matmul`` / the engine registry rather than importing this
@@ -12,7 +13,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import kernel_block
+from repro.kernels import gemm_block
 
 from .tiled_mm import tiled_mm_pallas
 from .ref import tiled_mm_ref
@@ -35,18 +36,19 @@ def tiled_matmul(a: jax.Array, b: jax.Array, *,
                  tile: tuple[int, int, int] | int = (256, 256, 256),
                  out_dtype=None,
                  interpret: bool = False) -> jax.Array:
-    """act(A @ B + bias) for arbitrary (m, k) x (k, n): runs the job tile
-    as its :func:`~repro.kernels.kernel_block`, pads to block multiples
-    (the fixed-size PE's zero-padded border jobs) and slices the valid
-    region back out."""
-    tile = kernel_block(tile)
+    """act(A @ B + bias) for arbitrary (m, k) x (k, n): runs the block
+    :func:`~repro.kernels.gemm_block` picks for the shape, at least the
+    job tile's :func:`~repro.kernels.kernel_block`.  K and N are
+    zero-padded (the fixed-size PE's border jobs) and the valid columns
+    sliced back out only where that block does not divide them; a ragged
+    last row block needs neither."""
     m, k = a.shape
     _, n = b.shape
-    ts_m, ts_n, ts_k = tile
-    a_p = _pad_to(a, (ts_m, ts_k))
-    b_p = _pad_to(b, (ts_k, ts_n))
-    bias_p = _pad_to(bias, (ts_n,)) if bias is not None else None
+    bm, bn, bk = gemm_block(m, k, n, a.dtype.itemsize, tile)
+    a_p = _pad_to(a, (1, bk))
+    b_p = _pad_to(b, (bk, bn))
+    bias_p = _pad_to(bias, (bn,)) if bias is not None else None
     y = tiled_mm_pallas(a_p, b_p, bias=bias_p, activation=activation,
-                        tile=tile, out_dtype=out_dtype,
+                        tile=(bm, bn, bk), out_dtype=out_dtype,
                         interpret=interpret or jax.default_backend() != "tpu")
-    return y[:m, :n]
+    return y if y.shape[1] == n else y[:, :n]

@@ -13,12 +13,16 @@ TPU mapping:
                              prefetch of block k+1 during compute of block k).
   * loop pipelining / MXU -> jnp.dot on (ts_m, ts_k)x(ts_k, ts_n) blocks
                              with fp32 accumulation in a VMEM scratch.
-  * border zero-padding   -> operands padded to tile multiples in ops.py
-                             (functionally identical to the paper's masked
-                             loads/stores; XLA pads are free on HBM).
-  * job == grid cell      -> grid (gm, gn, gk); (i, j) is the paper's
-                             (t1, t2) tile index; the TPU core scheduler
-                             plays the role of the cluster dispatcher.
+  * border zero-padding   -> a ragged last row block (its rows are
+                             independent, its stores masked); K and N
+                             padded in ops.py only where no block of the
+                             shape divides them.
+  * jobs -> grid cells    -> grid (gm, gn, gk) of the block
+                             ``repro.kernels.gemm_block`` picks from the
+                             GEMM's shape: a grid cell holds many of the
+                             paper's (t1, t2) job tiles, which stay the
+                             unit of splitting and accounting; the TPU
+                             core scheduler plays the cluster dispatcher.
 
 Beyond the paper: a fused epilogue (bias + activation) saves one HBM round
 trip per GEMM; the k dimension is marked "arbitrary" and m/n "parallel" so
@@ -34,6 +38,8 @@ import jax
 import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import GEMM_VMEM_LIMIT
 
 __all__ = ["tiled_mm_pallas"]
 
@@ -63,15 +69,16 @@ def tiled_mm_pallas(a: jax.Array, b: jax.Array, *,
                     tile: tuple[int, int, int] = (256, 256, 256),
                     out_dtype=None,
                     interpret: bool = False) -> jax.Array:
-    """C[m, n] = act(A[m, k] @ B[k, n] + bias).  Dims must be multiples of
-    ``tile`` (ops.py pads borders — the paper's zero-padding)."""
+    """C[m, n] = act(A[m, k] @ B[k, n] + bias) by ``tile`` blocks.  N and
+    K must be multiples of the tile (ops.py pads them where needed — the
+    paper's zero-padding); the last row block may be ragged."""
     m, k = a.shape
     k2, n = b.shape
     assert k == k2
     ts_m, ts_n, ts_k = tile
-    assert m % ts_m == 0 and n % ts_n == 0 and k % ts_k == 0, (
-        f"padded dims required: {(m, n, k)} vs tile {tile}")
-    gm, gn, gk = m // ts_m, n // ts_n, k // ts_k
+    assert n % ts_n == 0 and k % ts_k == 0, (
+        f"padded dims required: {(n, k)} vs tile {tile}")
+    gm, gn, gk = pl.cdiv(m, ts_m), n // ts_n, k // ts_k
     out_dtype = out_dtype or a.dtype
 
     has_bias = bias is not None
@@ -95,7 +102,8 @@ def tiled_mm_pallas(a: jax.Array, b: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((ts_m, ts_n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=GEMM_VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(flops=flops,
                                       bytes_accessed=bytes_accessed,
                                       transcendentals=0),

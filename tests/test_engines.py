@@ -16,6 +16,8 @@ from repro.engines import (CAP_GEMM, CostModel, Dispatcher, Engine,
                            registered, resolve_op)
 from repro.models.cnn import cnn_forward, init_cnn
 
+from _gemms import MODEL_GEMMS
+
 
 def _ab(m, k, n, seed=0):
     ka, kb = jax.random.split(jax.random.key(seed))
@@ -32,7 +34,9 @@ def test_builtin_engines_registered():
 
 @pytest.mark.parametrize("shape", [(64, 64, 64),      # tile-aligned
                                    (70, 45, 33),      # border tiles
-                                   (1, 257, 129)])
+                                   (1, 257, 129)] + [
+    (m, k, n) for m in (1, 196, 200) for k in (75, 147)   # no 128 multiple
+    for n in (10, 64, 1000)])
 def test_engines_agree_numerically(shape):
     """XLA, Pallas (interpret off-TPU), and the reference oracle compute
     the same GEMM, bias and activation included."""
@@ -248,6 +252,29 @@ def test_training_skips_variants_without_vjp(arch, op):
 def test_kernel_block_is_tpu_tileable(tile, block):
     from repro.kernels import kernel_block
     assert kernel_block(tile) == block
+
+
+@pytest.mark.parametrize("shape", sorted(
+    {s for shapes in MODEL_GEMMS.values() for s in shapes}
+    | {(1, 4096, 4096), (8, 2048, 49155), (70, 45, 33), (1, 257, 129),
+       (100_000, 100_003, 7)}))
+def test_gemm_block_is_tpu_tileable_and_fits_vmem(shape):
+    """At every operand width and job tile, each edge of the block the
+    tiled GEMM runs is the whole dimension or a multiple of the chip's
+    tiling, no less than the job tile's block where the dimension is
+    larger; K and N need padding only up to a multiple of that block;
+    A, B, the output and the f32 accumulator fit the kernel's VMEM."""
+    from repro.kernels import (_GEMM_VMEM_BUDGET, _gemm_vmem, gemm_block,
+                               kernel_block)
+    m, k, n = shape
+    for itemsize in (4, 2, 1):
+        for tile in (32, (256, 256, 256)):
+            blk = gemm_block(m, k, n, itemsize, tile)
+            for d, b, b0, mult in zip((m, n, k), blk, kernel_block(tile),
+                                      (32, 128, 128)):
+                assert b == d or (b % mult == 0 and b >= b0), (d, blk)
+                assert b == d or b <= -(-d // b0) * b0, (d, blk)
+            assert _gemm_vmem(*blk, itemsize) <= _GEMM_VMEM_BUDGET, blk
 
 
 # ------------------------------------------------- scheduler/registry view

@@ -528,6 +528,36 @@ def test_chunked_conv_graph_chunks_through_runtime():
     assert st_many.job_busy_s["prefill"] > 0
 
 
+def test_run_serves_every_request_while_a_conv_chain_lags(monkeypatch):
+    """A conv chunk that finishes after the live decoders (a loaded host)
+    leaves a step with nothing to do but wait: ``run()`` waits for the
+    chain and admits the queued requests instead of returning without
+    them."""
+    from repro.core import serving
+    from repro.soc import SynergyRuntime
+
+    advance, polls = serving.SynergyServer._advance_conv, {}
+
+    def lagging(self, conv):
+        if conv is not None and conv.fut is not None:
+            polls[id(conv.fut)] = polls.get(id(conv.fut), 0) + 1
+            if polls[id(conv.fut)] <= 40:
+                return False
+        return advance(self, conv)
+
+    monkeypatch.setattr(serving.SynergyServer, "_advance_conv", lagging)
+    with SynergyRuntime(["F-PE", "S-PE"], name="lagging") as rt:
+        srv = _server(slots=2, runtime=rt, prefill_cnn=TINY_CNN,
+                      prefill_chunk_macs=150_000)
+        reqs = [Request(i, jnp.arange(4, dtype=jnp.int32) + i,
+                        max_new_tokens=3 + i) for i in range(4)]
+        for r in reqs:
+            srv.submit(r)
+        stats = srv.run()
+    assert [len(r.out) for r in reqs] == [3, 4, 5, 6]
+    assert stats.prefills == 4
+
+
 # ------------------------------------------------- real FFN decode weights
 
 def test_decode_weight_stacks_real_ffn_layers():

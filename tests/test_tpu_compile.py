@@ -5,7 +5,8 @@ Nothing here runs: each test lowers and compiles for one chip of a
 attached, so what Mosaic or XLA refuses on the chip (block shapes that do
 not tile, kernels that do not lower, a Pallas kernel under
 differentiation) fails here at no chip time.  Shapes are the published
-widths of granite-3-2b and mamba2-130m.
+widths of granite-3-2b and mamba2-130m, and every GEMM of the benchmark's
+CNN calls.
 
 The topology is described inside a fixture and never while a module is
 imported: the TPU library admits one process at a time, and test workers
@@ -24,7 +25,7 @@ from repro.configs import ARCHS
 from repro.core.serving import _SERVE_TILE
 from repro.core.synergy_mm import DEFAULT_TILE
 from repro.engines import op_variants, register_op_impl
-from repro.kernels import kernel_block
+from repro.kernels import gemm_block, kernel_block
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.qmm import qmm_pallas
 from repro.kernels.ssd import ssd
@@ -32,6 +33,8 @@ from repro.kernels.ssd.ssd import ssd_pallas
 from repro.kernels.tiled_mm import tiled_mm_pallas
 from repro.kernels.vpu_mm import vpu_mm_pallas
 from repro.models.attention import attention
+
+from _gemms import MODEL_GEMMS
 
 GRANITE = ARCHS["granite-3-2b"]
 MAMBA = ARCHS["mamba2-130m"]
@@ -104,6 +107,35 @@ def test_tiled_mm_compiles_at_engine_block(one_chip, tile, dtype):
     blk = kernel_block(tile)
     _compile(lambda a, b: tiled_mm_pallas(a, b, tile=blk,
                                           activation=jax.nn.relu), a, b)
+
+
+#: granite's decode GEMMs at 8 slots: the MLP both ways and the LM head,
+#: whose odd vocabulary the wrapper pads where the block does not hold it
+_SERVE_GEMMS = [(8, GRANITE.d_model, GRANITE.d_ff),
+                (8, GRANITE.d_ff, GRANITE.d_model),
+                (8, GRANITE.d_model, GRANITE.vocab_size)]
+
+
+@pytest.mark.parametrize("m,k,n,dtype", [
+    pytest.param(*s, jnp.float32, id=f"{cfg}-{'x'.join(map(str, s))}")
+    for cfg, shapes in MODEL_GEMMS.items() for s in shapes] + [
+    pytest.param(*s, dtype, id=f"serve-{'x'.join(map(str, s))}-{name}")
+    for s in _SERVE_GEMMS
+    for dtype, name in ((jnp.float32, "f32"), (jnp.bfloat16, "bf16"))])
+def test_tiled_mm_compiles_at_gemm_block(one_chip, m, k, n, dtype):
+    """Each f32 GEMM of the CNN cells, unpadded, and the serving GEMMs,
+    padded as the wrapper pads them, at the block the wrapper picks:
+    Mosaic refuses a block that does not tile or that overflows the
+    kernel's VMEM."""
+    blk = gemm_block(m, k, n, jnp.dtype(dtype).itemsize, _SERVE_TILE)
+    _, bn, bk = blk
+    k, n = -(-k // bk) * bk, -(-n // bn) * bn
+    a = _spec(one_chip, (m, k), dtype)
+    b = _spec(one_chip, (k, n), dtype)
+    bias = _spec(one_chip, (n,), dtype)
+    compiled = _compile(lambda a, b, bias: tiled_mm_pallas(
+        a, b, bias=bias, activation=jax.nn.relu, tile=blk), a, b, bias)
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("tile", [_SERVE_TILE, DEFAULT_TILE],
